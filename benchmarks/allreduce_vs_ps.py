@@ -15,6 +15,7 @@ from typing import List, Tuple
 _PROG = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ranks}"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import transformer as T

@@ -17,14 +17,17 @@ import sys
 _PROG = r"""
 import os, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp
 from repro import optim
 from repro.configs import get_config
 from repro.configs.base import SHAPES
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import device_peaks, make_production_mesh
 from repro.launch.dryrun import (_compile_costs, _group_counts,
                                  collective_bytes, collective_bytes_by_scope)
 from repro.distributed import stepfn
+
+PEAKS = device_peaks("TPU v5 lite")     # the production mesh's chips
 
 def terms(cfg, shape_name, strategy, **step_kw):
     shape = SHAPES[shape_name]
@@ -45,8 +48,9 @@ def terms(cfg, shape_name, strategy, **step_kw):
                     float(cost.get("bytes accessed", 0)),
                     float(sum(collective_bytes(comp.as_text()).values()))))
     ex = lambda i: out[0][i] + (G - 1) * (out[1][i] - out[0][i])
-    return {"compute_ms": ex(0)/197e12*1e3, "memory_ms": ex(1)/819e9*1e3,
-            "collective_ms": ex(2)/50e9*1e3}
+    return {"compute_ms": ex(0)/PEAKS["flops_bf16"]*1e3,
+            "memory_ms": ex(1)/PEAKS["hbm_bytes_per_s"]*1e3,
+            "collective_ms": ex(2)/PEAKS["ici_bytes_per_s_per_link"]*1e3}
 
 def emit(pair, name, t):
     print("ROW " + json.dumps({"pair": pair, "iter": name, **t}), flush=True)

@@ -22,9 +22,10 @@ import subprocess
 import sys
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+from repro.launch.mesh import device_peaks
+
+# the dry-run meshes describe TPU v5e chips
+_PEAKS = device_peaks("TPU v5 lite")
 
 
 def model_flops_per_device(arch: str, shape_name: str, chips: int = 256) -> float:
@@ -47,9 +48,9 @@ def model_flops_per_device(arch: str, shape_name: str, chips: int = 256) -> floa
 
 def terms(rec: Dict) -> Dict:
     f, b, cb = rec["flops"], rec["bytes_accessed"], rec["collective_bytes_total"]
-    t_c = f / PEAK_FLOPS
-    t_m = b / HBM_BW
-    t_x = cb / ICI_BW
+    t_c = f / _PEAKS["flops_bf16"]
+    t_m = b / _PEAKS["hbm_bytes_per_s"]
+    t_x = cb / _PEAKS["ici_bytes_per_s_per_link"]
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
               key=lambda kv: kv[1])[0]
     mf = model_flops_per_device(rec["arch"], rec["shape"], rec["chips"])
@@ -87,6 +88,7 @@ def run_sweep(out_path: str, pairs: Optional[List] = None) -> List[Dict]:
     prog = """
 import os, json, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 from repro.launch.dryrun import roofline_pair
 from repro.configs import ARCHS
 from repro.configs.base import SHAPES

@@ -309,14 +309,16 @@ def main() -> None:
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
 
+    # a failed phase fails the run, with or without --check
+    errors = [f"{name}: benchmark failed" for name, _, _ in rows
+              if name.endswith("/FAILED")]
     if args.check:
-        errors = [f"{name}: benchmark failed" for name, _, _ in rows
-                  if name.endswith("/FAILED")]
         errors += _check_bench_json()
-        if errors:
-            for e in errors:
-                print(f"CHECK FAILED: {e}", file=sys.stderr)
-            sys.exit(1)
+    if errors:
+        for e in errors:
+            print(f"CHECK FAILED: {e}", file=sys.stderr)
+        sys.exit(1)
+    if args.check:
         print("check: all BENCH_*.json artifacts healthy")
 
 

@@ -79,6 +79,7 @@ def measured_allreduce_bytes(ranks: int) -> int:
     prog = f"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ranks}"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.models import gan3d as G
@@ -102,7 +103,7 @@ ds_s = jax.eval_shape(d_opt.init, dp_s)
 batch_s = {{"images": jax.ShapeDtypeStruct((B,25,25,25,1), jnp.float32),
            "energies": jax.ShapeDtypeStruct((B,), jnp.float32)}}
 z_s = jax.ShapeDtypeStruct((B, cfg.latent_dim), jnp.float32)
-f = jax.jit(hvd.shard_map(local, mesh=mesh,
+f = jax.jit(jax.shard_map(local, mesh=mesh,
     in_specs=(P(), P(), P(), {{"images": P("data"), "energies": P("data")}}, P("data")),
     out_specs=(P(), P()), check_vma=False))
 c = f.lower(dp_s, ds_s, gp_s, batch_s, z_s).compile()

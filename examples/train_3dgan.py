@@ -37,7 +37,7 @@ def make_gan_steps(cfg, mesh, d_opt, g_opt):
         return optim.apply_updates(gp, upd), gs, hvd.allreduce(m, ("data",))
 
     def shard(fn, n_out=3):
-        return jax.jit(hvd.shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(), P(), P(), {"images": P("data"), "energies": P("data")},
                       P("data")),

@@ -25,21 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                    # jax >= 0.6 exposes it at top level
-    shard_map = jax.shard_map
-except AttributeError:                  # older jax: experimental namespace,
-    from jax.experimental import shard_map as _esm  # check_vma was check_rep
-
-    def shard_map(f, **kw):
-        kw["check_rep"] = kw.pop("check_vma", True)
-        return _esm.shard_map(f, **kw)
-
-if hasattr(lax, "axis_size"):
-    _axis_size = lax.axis_size
-else:                                   # pre-axis_size jax: psum of a literal
-    def _axis_size(ax):                 # constant-folds to a static int
-        return lax.psum(1, ax)
-
 
 # ---------------------------------------------------------------------------
 # Inside-shard_map collective API (Horovod vocabulary)
@@ -49,14 +34,14 @@ def rank(axes: Sequence[str]) -> jnp.ndarray:
     """Linearized rank across ``axes`` (row-major, like MPI_Comm_rank)."""
     r = jnp.zeros((), jnp.int32)
     for ax in axes:
-        r = r * _axis_size(ax) + lax.axis_index(ax)
+        r = r * lax.axis_size(ax) + lax.axis_index(ax)
     return r
 
 
 def size(axes: Sequence[str]) -> int:
     s = 1
     for ax in axes:
-        s *= _axis_size(ax)
+        s *= lax.axis_size(ax)
     return s
 
 
@@ -88,10 +73,10 @@ def hierarchical_allreduce(x, inner: Sequence[str], outer: Sequence[str],
     inner, outer = tuple(inner), tuple(outer)
     n_inner = 1
     for ax in inner:
-        n_inner *= _axis_size(ax)
+        n_inner *= lax.axis_size(ax)
     denom = float(n_inner)
     for ax in outer:
-        denom *= _axis_size(ax)
+        denom *= lax.axis_size(ax)
 
     def per_leaf(a):
         flat = a.reshape(-1)
@@ -185,7 +170,7 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
         return params, opt_state, metrics
 
     def step(params, opt_state, batch):
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(P(), P(), _batch_specs(batch, axes)),
             out_specs=(P(), P(), P()),
@@ -204,7 +189,7 @@ def make_eval_step(loss_fn: Callable, mesh: Mesh,
         return allreduce(dict(metrics, loss=loss), axes, average=True)
 
     def step(params, batch):
-        return shard_map(
+        return jax.shard_map(
             local_eval, mesh=mesh,
             in_specs=(P(), _batch_specs(batch, axes)),
             out_specs=P(), check_vma=False)(params, batch)

@@ -53,7 +53,7 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
         return new_params, opt_state, metrics
 
     def step(params, opt_state, batch):
-        return hvd.shard_map(
+        return jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(P(), P(), jax.tree.map(lambda _: P(tuple(axes)), batch)),
             out_specs=(P(), P(), P()),

@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These are the model-facing entry points: they handle head folding/GQA
-layout, choose interpret mode automatically off-TPU (CPU validation per
-the brief), and are shape-polymorphic over the model stacks' layouts.
+layout, choose interpret mode on the CPU (validation) and compiled mode
+on the TPU, and are shape-polymorphic over the model stacks' layouts.
 """
 from __future__ import annotations
 
@@ -20,7 +20,14 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on the TPU, interpreted on the CPU (validation only);
+    any other backend is an error, never a silent interpreter run."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                           f"{backend!r}: use a TPU, or the CPU for "
+                           f"interpret-mode validation")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",

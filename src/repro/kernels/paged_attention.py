@@ -10,15 +10,19 @@ KV footprint tracks the tokens actually generated, not the worst case.
 TPU adaptation: the block table and per-sequence lengths ride in as
 *scalar-prefetch* operands (``pltpu.PrefetchScalarGridSpec``), so the
 page index feeding each K/V tile's DMA — ``table[b, i]`` — is known
-before the kernel body runs.  The grid is ``(B, KV, pages_per_seq)``
-with the page axis innermost and sequential, so the online-softmax state
-``(m, l, acc)`` accumulates in VMEM scratch across pages exactly like
-the flash-attention kernel accumulates across KV tiles.  Pages past a
+before the kernel body runs.  The grid is ``(B, pages_per_seq)`` with
+the page axis innermost and sequential, so the online-softmax state
+``(m, l, acc)`` of every KV head accumulates in VMEM scratch across
+pages exactly like the flash-attention kernel accumulates across KV
+tiles.  One K/V block is a whole page, ``(page_size, KV, D)``: Mosaic
+requires a block's last two dims to be (8, 128)-divisible or the
+array's own, so a one-head ``(page_size, 1, D)`` block is refused, and
+the kernel walks the KV heads of the page itself.  Pages past a
 sequence's length are skipped (their table entries point at the pool's
 trash page and the position mask kills any stray values).
 
-Features match the dense decode path: GQA (per-KV-head grid axis with
-all G query heads of the group in one tile), sliding window, and
+Features match the dense decode path: GQA (all G query heads of a KV
+head's group in one tile), sliding window, and
 attention-logit softcap.  Validated against
 ``repro.kernels.ref.paged_attention_ref`` in interpret mode (CPU), which
 is itself validated against a dense gather + softmax in the tests.
@@ -40,10 +44,10 @@ NEG_INF = -1.0e30
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float,
                   window: Optional[int], softcap: Optional[float],
-                  page_size: int):
+                  page_size: int, kv_heads: int):
     b = pl.program_id(0)
-    i = pl.program_id(2)
-    ni = pl.num_programs(2)
+    i = pl.program_id(1)
+    ni = pl.num_programs(1)
 
     @pl.when(i == 0)
     def _init():
@@ -62,36 +66,41 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(reachable)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)             # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)          # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < length                            # causal: q is last
-        if window is not None:
-            mask &= (q_pos - kpos) < window
-        s = jnp.where(mask, s, NEG_INF)
+        # one page block carries every KV head; each head's G query
+        # heads attend its (page, D) slice
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)             # (G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)       # (page, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = kpos < length                        # causal: q is last
+            if window is not None:
+                mask &= (q_pos - kpos) < window
+            s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]                             # (G,)
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            m_prev = m_ref[h]                           # (G, 1)
+            l_prev = l_ref[h]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            alpha = jnp.where(m_prev == NEG_INF, 0.0,
+                              jnp.exp(m_prev - m_new))
+            l_ref[h] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(i == ni - 1)
     def _finalize():
         l = l_ref[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
@@ -122,26 +131,28 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, pages_per_seq),
+        grid=(B, pages_per_seq),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, i, tbl, lens:
-                         (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, D), lambda b, h, i, tbl, lens:
-                         (tbl[b, i], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, D), lambda b, h, i, tbl, lens:
-                         (tbl[b, i], 0, h, 0)),
+            pl.BlockSpec((1, KV, G, D), lambda b, i, tbl, lens:
+                         (b, 0, 0, 0)),
+            # all KV heads of one page per block: the block's last two
+            # dims (KV, D) are the array's own, as Mosaic requires
+            pl.BlockSpec((1, page_size, KV, D), lambda b, i, tbl, lens:
+                         (tbl[b, i], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, KV, D), lambda b, i, tbl, lens:
+                         (tbl[b, i], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, i, tbl, lens:
-                               (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, D), lambda b, i, tbl, lens:
+                               (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),          # running max m
-            pltpu.VMEM((G,), jnp.float32),          # running denom l
-            pltpu.VMEM((G, D), jnp.float32),        # output accumulator
+            pltpu.VMEM((KV, G, 1), jnp.float32),    # running max m
+            pltpu.VMEM((KV, G, 1), jnp.float32),    # running denom l
+            pltpu.VMEM((KV, G, D), jnp.float32),    # output accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, window=window,
-                          softcap=softcap, page_size=page_size),
+                          softcap=softcap, page_size=page_size, kv_heads=KV),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,

@@ -14,12 +14,15 @@ TPU adaptation, mirroring the decode kernel: the block table and the
 per-row ``(start, q_len)`` scalars ride in as *scalar-prefetch* operands
 (``pltpu.PrefetchScalarGridSpec``), so the page id feeding each K/V
 tile's DMA — ``table[b, i]`` — is known before the kernel body runs.
-The grid is ``(B, KV, pages_per_seq)`` with the page axis innermost and
-sequential; the online-softmax state ``(m, l, acc)`` accumulates in VMEM
-scratch across pages.  The query tile folds ``(chunk, G)`` into one
-``CG = chunk * G`` axis (row ``c * G + g``), so GQA costs one page DMA
-per KV head per page, never per query head; the per-row chunk index is
-recovered in-kernel as ``row // G`` for the causal mask.
+The grid is ``(B, pages_per_seq)`` with the page axis innermost and
+sequential; the online-softmax state ``(m, l, acc)`` of every KV head
+accumulates in VMEM scratch across pages.  One K/V block is a whole
+page, ``(page_size, KV, D)`` (a one-head block's last two dims would
+not be legal for Mosaic), and the kernel walks the page's KV heads.
+The query tile folds ``(chunk, G)`` into one ``CG = chunk * G`` axis
+(row ``c * G + g``), so GQA costs one page DMA per page, never per
+query head; the per-row chunk index is recovered in-kernel as
+``row // G`` for the causal mask.
 
 Pages holding no attended position — entirely past the newest query, or
 entirely outside the sliding window of the *oldest* query in the tile —
@@ -46,10 +49,10 @@ NEG_INF = -1.0e30
 def _paged_prefill_kernel(tbl_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
                           o_ref, m_ref, l_ref, acc_ref, *, scale: float,
                           window: Optional[int], softcap: Optional[float],
-                          page_size: int, group: int):
+                          page_size: int, group: int, kv_heads: int):
     b = pl.program_id(0)
-    i = pl.program_id(2)
-    ni = pl.num_programs(2)
+    i = pl.program_id(1)
+    ni = pl.num_programs(1)
 
     @pl.when(i == 0)
     def _init():
@@ -69,43 +72,47 @@ def _paged_prefill_kernel(tbl_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
 
     @pl.when(reachable)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)             # (CG, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)          # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        # row c*G+g is query token c of the chunk (all G heads of a group
-        # share one causal row)
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-        qpos = start + qi
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (kpos <= qpos) & (qi < q_len)
-        if window is not None:
-            mask &= (qpos - kpos) < window
-        s = jnp.where(mask, s, NEG_INF)
+        # one page block carries every KV head; each head's CG query
+        # rows attend its (page, D) slice
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)             # (CG, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)       # (page, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            # row c*G+g is query token c of the chunk (all G heads of a
+            # group share one causal row)
+            qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+            qpos = start + qi
+            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = (kpos <= qpos) & (qi < q_len)
+            if window is not None:
+                mask &= (qpos - kpos) < window
+            s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]                             # (CG,)
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # a fully-masked row (padding query) has m_new == NEG_INF; its
-        # probabilities must be 0, not exp(NEG_INF - NEG_INF) = 1
-        p = jnp.where(m_new[:, None] == NEG_INF, 0.0,
-                      jnp.exp(s - m_new[:, None]))
-        alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            m_prev = m_ref[h]                           # (CG, 1)
+            l_prev = l_ref[h]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            # a fully-masked row (padding query) has m_new == NEG_INF; its
+            # probabilities must be 0, not exp(NEG_INF - NEG_INF) = 1
+            p = jnp.where(m_new == NEG_INF, 0.0, jnp.exp(s - m_new))
+            alpha = jnp.where(m_prev == NEG_INF, 0.0,
+                              jnp.exp(m_prev - m_new))
+            l_ref[h] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(i == ni - 1)
     def _finalize():
         l = l_ref[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def paged_prefill(q, k_pages, v_pages, block_tables, start_pos, q_lens, *,
@@ -142,26 +149,29 @@ def paged_prefill(q, k_pages, v_pages, block_tables, start_pos, q_lens, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, KV, pages_per_seq),
+        grid=(B, pages_per_seq),
         in_specs=[
-            pl.BlockSpec((1, 1, CG, D), lambda b, h, i, tbl, st, ql:
-                         (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, D), lambda b, h, i, tbl, st, ql:
-                         (tbl[b, i], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, D), lambda b, h, i, tbl, st, ql:
-                         (tbl[b, i], 0, h, 0)),
+            pl.BlockSpec((1, KV, CG, D), lambda b, i, tbl, st, ql:
+                         (b, 0, 0, 0)),
+            # all KV heads of one page per block: the block's last two
+            # dims (KV, D) are the array's own, as Mosaic requires
+            pl.BlockSpec((1, page_size, KV, D), lambda b, i, tbl, st, ql:
+                         (tbl[b, i], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, KV, D), lambda b, i, tbl, st, ql:
+                         (tbl[b, i], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, CG, D), lambda b, h, i, tbl, st, ql:
-                               (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, CG, D), lambda b, i, tbl, st, ql:
+                               (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((CG,), jnp.float32),         # running max m
-            pltpu.VMEM((CG,), jnp.float32),         # running denom l
-            pltpu.VMEM((CG, D), jnp.float32),       # output accumulator
+            pltpu.VMEM((KV, CG, 1), jnp.float32),   # running max m
+            pltpu.VMEM((KV, CG, 1), jnp.float32),   # running denom l
+            pltpu.VMEM((KV, CG, D), jnp.float32),   # output accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(_paged_prefill_kernel, scale=scale, window=window,
-                          softcap=softcap, page_size=page_size, group=group),
+                          softcap=softcap, page_size=page_size, group=group,
+                          kv_heads=KV),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, CG, D), q.dtype),
         interpret=interpret,

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"       # a CPU analysis: never open the chip
 
 """Multi-pod dry-run: prove the distribution config is coherent.
 
@@ -27,14 +28,6 @@ from typing import Dict, Optional
 
 import jax
 import numpy as np
-
-
-def _cost_dict(cost) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns a dict on current jax but a
-    one-element list of dicts on older releases — normalize to a dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +140,7 @@ def dryrun_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
         lowered = jitted.lower(*structs)
         compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     n_chips = int(np.prod(list(mesh.shape.values())))
     rec = {
@@ -208,7 +201,7 @@ def _compile_costs(cfg, shape, mesh, strategy):
     jitted, structs = stepfn.make_step_for_shape(cfg, mesh, strategy, shape)
     with mesh, jax.transfer_guard("disallow"):
         compiled = jitted.lower(*structs).compile()
-    cost = _cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),

@@ -14,19 +14,13 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-try:                                   # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with explicit Auto axis types (sharding follows
+    the compiler's propagation, as the step functions expect)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 _mesh = make_mesh
@@ -44,7 +38,21 @@ def make_host_mesh(data: Optional[int] = None):
     return _mesh((n,), ("data",))
 
 
-# TPU v5e hardware constants (per chip) used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # bytes/s
-ICI_BW = 50e9                     # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture
+# table): 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect, which the roofline's collective term splits
+# over the chip's four ICI links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9, "ici_bytes_per_s_per_link": 50e9},
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Published peaks of one chip; a device not in ``PEAKS`` is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to PEAKS with a source")
+    return PEAKS[device_kind]

@@ -54,7 +54,10 @@ def main(argv=None):
                          "(LocalProcessBackend), 'mock' = deterministic "
                          "in-process workers (MockBackend); requires "
                          "--smoke — workers rebuild bit-identical weights "
-                         "from the declarative smoke spec")
+                         "from the declarative smoke spec.  Subprocess "
+                         "workers run on the CPU (JAX_PLATFORMS=cpu): a "
+                         "chip belongs to one process, and this fleet is "
+                         "smoke-size only")
     ap.add_argument("--spool", default=None, metavar="DIR",
                     help="fabric spool directory "
                          "(default: results/fabric-spool)")
@@ -263,14 +266,18 @@ def main(argv=None):
                   f"churning: {rs['churning'] or 'none'}")
         if args.paged:
             from repro.serving import profile_paged_kernels
+            def share(frac):
+                return "not measured" if frac is None else f"{frac:.1%}"
+
             for name, prof in profile_paged_kernels(
                     gateway.replicas[0].scheduler.engine).items():
-                print(f"kernel {name}: {prof['wall_ms_median']:.2f} ms, "
+                print(f"kernel {name} [{prof['device']}]: "
+                      f"{prof['wall_ms_median']:.2f} ms, "
                       f"{prof['flops']:.3g} flops, "
                       f"{prof['achieved_tflops']:.3f} TFLOP/s "
-                      f"({prof['fraction_of_peak_flops']:.1%} of peak), "
+                      f"({share(prof['fraction_of_peak_flops'])} of peak), "
                       f"{prof['achieved_gbps']:.1f} GB/s "
-                      f"({prof['fraction_of_peak_bw']:.1%} of HBM)")
+                      f"({share(prof['fraction_of_peak_bw'])} of HBM)")
     if args.metrics_json:
         with open(args.metrics_json, "w") as f:
             json.dump(stats, f, indent=2, sort_keys=True, default=str)
@@ -304,4 +311,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

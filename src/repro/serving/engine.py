@@ -151,14 +151,21 @@ class ServingEngine:
     that lets one replica serve more concurrent sequences than the dense
     layout at the same KV budget.  Requires a positional, non-int8
     attention cache (dense / MoE / VLM families).
+
+    ``device`` places the engine's parameters and KV pool on one device
+    (several one-chip replicas in one process); every program the engine
+    runs then follows them there.  ``None`` keeps JAX's default device.
     """
 
     def __init__(self, cfg, params, max_seq_len: int, max_slots: int = 8,
                  rng_seed: int = 0, kv_block_size: int = 16,
                  prefix_cache_blocks: int = 0, prefill_chunk: int = 16,
                  paged: bool = False, num_blocks: Optional[int] = None,
-                 prefill_batch: int = 4, greedy_tie_eps: float = 1e-2):
+                 prefill_batch: int = 4, greedy_tie_eps: float = 1e-2,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
+        if device is not None:
+            params = jax.device_put(params, device)
         self.params = params
         self.max_seq_len = max_seq_len
         self.max_slots = max_slots
@@ -177,11 +184,18 @@ class ServingEngine:
         self.prefill_batch = max(1, min(prefill_batch, max_slots))
         self.paged = paged
         want_prefix = prefix_cache_blocks > 0
-        self.kv = PagedKVCache(
-            cfg, max_slots, max_seq_len, block_size=kv_block_size,
-            prefix_blocks=(prefix_cache_blocks if want_prefix and
-                           self._family_supports_prefix(cfg) else 0),
-            num_blocks=num_blocks, paged=paged)
+        with jax.default_device(device):
+            self.kv = PagedKVCache(
+                cfg, max_slots, max_seq_len, block_size=kv_block_size,
+                prefix_blocks=(prefix_cache_blocks if want_prefix and
+                               self._family_supports_prefix(cfg) else 0),
+                num_blocks=num_blocks, paged=paged)
+        if device is not None:
+            # committed: host inputs (tokens, tables) follow these arrays
+            self.kv.cache = jax.device_put(self.kv.cache, device)
+            if self.kv.prefix_store is not None:
+                self.kv.prefix_store = jax.device_put(self.kv.prefix_store,
+                                                      device)
         self.prefix_cache = None
         if self.kv.prefix_pool is not None:
             from repro.serving.prefix_cache import PrefixCache

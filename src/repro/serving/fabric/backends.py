@@ -215,7 +215,12 @@ class MockBackend(SchedulerBackend):
 class LocalProcessBackend(SchedulerBackend):
     """Real subprocess workers: ``python -m repro.serving.fabric.worker``
     per replica, talking through the same spool.  The integration
-    backend — kill(-9)able, genuinely concurrent."""
+    backend — kill(-9)able, genuinely concurrent.
+
+    Workers run on the CPU (``JAX_PLATFORMS=cpu``): the gateway's own
+    process may already hold the accelerator, and a chip belongs to one
+    process at a time.  This backend is for smoke-size fleets; giving
+    each worker a chip of its own is not implemented."""
 
     def __init__(self, registry: Optional[ClusterRegistry] = None):
         super().__init__(registry)
@@ -229,6 +234,7 @@ class LocalProcessBackend(SchedulerBackend):
         env = dict(os.environ)
         prev = env.get("PYTHONPATH")
         env["PYTHONPATH"] = src + (os.pathsep + prev if prev else "")
+        env["JAX_PLATFORMS"] = "cpu"
         return env
 
     def _launch(self, handle: JobHandle) -> None:

@@ -13,12 +13,11 @@ an operator needs to localize a slowdown are built in:
 * :func:`profile_kernel` / :func:`profile_paged_kernels` — per-kernel
   profiles for the paged attention kernels at serving shapes: compiled
   ``cost_analysis()`` FLOPs/bytes plus measured wall time, reduced to
-  achieved fractions of the roofline peaks (``benchmarks/roofline.py``'s
-  constants when importable; the same v5p numbers inlined as a fallback
-  because ``benchmarks/`` is not a package on the capsule's path).  On
-  CPU the kernels run in interpret mode, so the achieved fractions are
-  meaningful only on real hardware — the *structure* (flops > 0, bytes >
-  0, wall > 0) is what tests pin.
+  achieved fractions of the device's published peaks
+  (``repro.launch.mesh.PEAKS``, keyed by ``device_kind``; a device not
+  in the table raises).  On the CPU the kernels run in interpret mode
+  and have no peak, so the fractions are ``None`` ("not measured") —
+  the *structure* (flops > 0, bytes > 0, wall > 0) is what tests pin.
 
 * :class:`RecompilationTracker` — jit recompilation telemetry.  XLA's
   jit cache keys on argument shapes/dtypes; a serving loop that lets a
@@ -38,12 +37,6 @@ import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.serving.slo import SlidingWindow
-
-try:                                    # repo-root runs (benchmarks/ CI)
-    from benchmarks.roofline import HBM_BW, PEAK_FLOPS
-except Exception:                       # in-capsule: same v5p peaks
-    PEAK_FLOPS = 197e12
-    HBM_BW = 819e9
 
 PHASES = ("admit", "prefill", "decode", "sample")
 
@@ -71,14 +64,6 @@ class StepProfiler:
         return out
 
 
-def _cost_dict(cost) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns a dict on current jax but a
-    one-element list of dicts on older releases — normalize to a dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def profile_kernel(fn: Callable, *args, name: str, reps: int = 5,
                    clock=time.perf_counter, **kwargs) -> Dict[str, object]:
     """Profile one jitted program at the given arguments.
@@ -86,12 +71,17 @@ def profile_kernel(fn: Callable, *args, name: str, reps: int = 5,
     Lowers+compiles once for ``cost_analysis()`` (FLOPs / bytes
     accessed), then times ``reps`` executions bracketed by
     ``block_until_ready`` and reports the median wall plus achieved
-    fractions of the roofline compute and bandwidth peaks."""
+    fractions of the device's compute and bandwidth peaks (``None`` on
+    the CPU, which has no published peak: not measured)."""
     import jax
 
+    from repro.launch.mesh import device_peaks
+
+    dev = jax.devices()[0]
+    peaks = None if dev.platform == "cpu" else device_peaks(dev.device_kind)
     compiled = jax.jit(fn).lower(*args, **kwargs).compile() \
         if not hasattr(fn, "lower") else fn.lower(*args, **kwargs).compile()
-    cost = _cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     jax.block_until_ready(fn(*args, **kwargs))      # warm the jit cache
@@ -106,14 +96,17 @@ def profile_kernel(fn: Callable, *args, name: str, reps: int = 5,
     achieved_bw = bytes_accessed / wall if wall > 0 else 0.0
     return {
         "name": name,
+        "device": dev.device_kind,
         "reps": len(walls),
         "wall_ms_median": wall * 1e3,
         "flops": flops,
         "bytes_accessed": bytes_accessed,
         "achieved_tflops": achieved_flops / 1e12,
         "achieved_gbps": achieved_bw / 1e9,
-        "fraction_of_peak_flops": achieved_flops / PEAK_FLOPS,
-        "fraction_of_peak_bw": achieved_bw / HBM_BW,
+        "fraction_of_peak_flops": (achieved_flops / peaks["flops_bf16"]
+                                   if peaks else None),
+        "fraction_of_peak_bw": (achieved_bw / peaks["hbm_bytes_per_s"]
+                                if peaks else None),
         "arithmetic_intensity": (flops / bytes_accessed
                                  if bytes_accessed > 0 else 0.0),
     }

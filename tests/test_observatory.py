@@ -448,7 +448,10 @@ def test_profile_paged_kernels_structure(qwen):
         assert prof["flops"] > 0.0
         assert prof["bytes_accessed"] > 0.0
         assert prof["arithmetic_intensity"] > 0.0
-        assert prof["fraction_of_peak_flops"] >= 0.0
+        # the CPU has no published peak: fractions are "not measured"
+        assert prof["device"] == "cpu"
+        assert prof["fraction_of_peak_flops"] is None
+        assert prof["fraction_of_peak_bw"] is None
     with pytest.raises(ValueError, match="paged"):
         profile_paged_kernels(_engine(qwen))   # dense engine refused
 
