@@ -1,0 +1,6 @@
+"""95th percentile of every inter-token gap in the window (ms)."""
+from perfbench import readers
+
+
+def read(ctx):
+    return readers.percentile(readers.gaps_ms(ctx), 95)

@@ -1,0 +1,6 @@
+"""Kernels: the paged decode kernel's share of its roofline (%)."""
+from perfbench import readers
+
+
+def read(ctx):
+    return readers.roofline(ctx, "paged_decode")
