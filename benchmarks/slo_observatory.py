@@ -1,11 +1,11 @@
 """Serving-observatory overhead benchmark: the full telemetry stack
-(per-tenant SLO monitoring + device-accurate step profiling + recompile
+(per-tenant SLO monitoring + span-timed step phases + recompile
 tracking) enabled vs disabled on the interleaved workload.
 
 The observatory is only admissible in the serving hot loop if it is
 effectively free and inert: the claim is that an armed observatory —
 enabled tracer, per-tenant sliding-window percentiles, an SLO monitor
-evaluated every step, ``block_until_ready``-bracketed phase timing and
+evaluated every step, step phases timed at their program spans and
 shape-signature recompile tracking — keeps wall clock within 5% of the
 bare metrics path on the interleaved prefill/decode workload, with
 greedy outputs bit-identical.
@@ -50,14 +50,13 @@ OVERHEAD_BOUND = 1.05
 FLEET_REQUESTS = 10
 
 
-def _serve(engine, cfg, budget, tracer, profile):
+def _serve(engine, cfg, budget, tracer):
     """One interleaved-workload run, requests labelled round-robin over
-    ``TENANTS``, through a scheduler wearing ``tracer`` (armed or not)
-    and optionally the step profiler — same code path either way."""
+    ``TENANTS``, through a scheduler wearing ``tracer`` (armed or not) —
+    same code path either way."""
     from repro.serving import Request, SamplingParams, Scheduler
     longs, bursts = _workload(cfg)
-    sched = Scheduler(engine, prefill_token_budget=budget, tracer=tracer,
-                      profile=profile)
+    sched = Scheduler(engine, prefill_token_budget=budget, tracer=tracer)
     n_sub = 0
 
     def sub(prompt, max_new):
@@ -90,7 +89,7 @@ def _fleet_rollup(engine_fn, cfg, budget, slo_config):
 
     gw = ReplicaGateway.from_engines(
         [engine_fn(), engine_fn()], prefill_token_budget=budget,
-        tracing=True, slo_config=slo_config, profile=True)
+        tracing=True, slo_config=slo_config)
     rng = np.random.default_rng(5)
     for i in range(FLEET_REQUESTS):
         gw.submit(Request(
@@ -126,7 +125,7 @@ def _breach_demo(engine, cfg, budget):
     tight = SLOConfig.from_dict({
         "default": {"ttft_p95_ms": 0.001, "min_samples": 1}})
     tracer = Tracer(enabled=True, slo=SLOMonitor(tight))
-    _serve(engine, cfg, budget, tracer, False)
+    _serve(engine, cfg, budget, tracer)
     breaches = tracer.slo.breaches
     events = [e for e in tracer.snapshot() if e["kind"] == "slo_breach"]
     assert breaches >= 1, "impossible TTFT policy did not breach"
@@ -173,7 +172,7 @@ def run(quick: bool = True, out_path: str = "BENCH_slo.json"):
     # workload compiles, so post-warm novelty below is a regression
     eng = engine()
     _warmup(eng, cfg)
-    _serve(eng, cfg, budget, armed_tracer(), True)   # warm discarded rep
+    _serve(eng, cfg, budget, armed_tracer())   # warm discarded rep
     eng.recompiles.mark_warm()
 
     off_walls, on_walls = [], []
@@ -185,11 +184,11 @@ def run(quick: bool = True, out_path: str = "BENCH_slo.json"):
         for mode in order:
             if mode == "off":
                 off_out, _off_sum, wall = _serve(eng, cfg, budget,
-                                                 Tracer(), False)
+                                                 Tracer())
                 off_walls.append(wall)
             else:
                 tr = armed_tracer()
-                on_out, on_sum, wall = _serve(eng, cfg, budget, tr, True)
+                on_out, on_sum, wall = _serve(eng, cfg, budget, tr)
                 on_walls.append(wall)
                 events_recorded = tr.emitted_events
 
@@ -252,7 +251,7 @@ def run(quick: bool = True, out_path: str = "BENCH_slo.json"):
         ("slo_observatory/disabled", off_wall * 1e6,
          f"interleaved workload, bare metrics path, median of {reps}"),
         ("slo_observatory/enabled", on_wall * 1e6,
-         f"SLO monitor + step profiler + recompile tracker on: "
+         f"SLO monitor + span-timed phases + recompile tracker on: "
          f"{(ratio - 1) * 100:+.1f}% wall vs bare "
          f"(bound {(OVERHEAD_BOUND - 1) * 100:.0f}%), bit-identical, "
          f"{recomp['post_warm_recompiles']} post-warm recompiles, "

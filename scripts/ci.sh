@@ -13,8 +13,8 @@
 # which trace_report.py --validate then schema-checks (every event: ts,
 # kind from the documented enum, step and/or rid) and renders with the
 # SLO + profile sections, failing on any empty one.  The slo benchmark
-# asserts the full observatory (per-tenant SLO monitor + step profiler +
-# recompile tracker) stays within the same 5% budget, bit-identical,
+# asserts the full observatory (per-tenant SLO monitor + span-timed step
+# phases + recompile tracker) stays within the same 5% budget, bit-identical,
 # with zero post-warm recompilations.
 # Usage: scripts/ci.sh [extra pytest args]
 # CI runs the full suite (including the slow-marked interleaved

@@ -49,7 +49,8 @@ from typing import Dict, List, Optional, Tuple
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.serving.metrics import _pct  # noqa: E402
-from repro.serving.tracing import EVENT_KINDS, validate_event  # noqa: E402
+from repro.serving.tracing import (EVENT_KINDS, SPANS,  # noqa: E402
+                                   validate_event)
 
 
 def load_events(path: Path) -> List[dict]:
@@ -300,22 +301,25 @@ def slo_section(events: List[dict], top: int) -> dict:
 
 def profile_section(events: List[dict], top: int) -> dict:
     """Step-phase wall percentiles from the ``engine_step`` events plus
-    jit ``recompile`` telemetry.  With the scheduler's ``profile=True``
-    the phase durations are device time (block_until_ready-bracketed);
-    otherwise they measure dispatch."""
+    jit ``recompile`` telemetry.  Each phase is a program span
+    (``tracing.SPANS``) timed on the host clock: it measures the host's
+    time in the phase, dispatch included; device time is in the
+    profiler's trace, joined on ``step``."""
     steps = [ev for ev in events if ev["kind"] == "engine_step"]
     print("\n== profile ==")
     data: dict = {"phases": {}, "recompiles": {}}
     if not steps:
         print("  no engine_step events recorded")
     else:
-        print("  phase     p50 ms    p95 ms    max ms   total s")
-        for phase in ("admit", "prefill", "decode", "sample"):
-            durs = [ev.get(f"dur_{phase}_s", 0.0) for ev in steps]
+        print("  phase                   p50 ms    p95 ms    max ms   total s")
+        for phase, field in SPANS.items():
+            if field is None:
+                continue
+            durs = [ev.get(field, 0.0) for ev in steps]
             st = _stats_ms(durs)
             st["total_s"] = sum(durs)
             data["phases"][phase] = st
-            print(f"  {phase:<8s} {st['p50']:7.3f}  {st['p95']:8.3f}"
+            print(f"  {phase:<22s} {st['p50']:7.3f}  {st['p95']:8.3f}"
                   f"  {st['max']:8.3f}  {st['total_s']:8.3f}")
     rec = [ev for ev in events if ev["kind"] == "recompile"]
     if rec:

@@ -110,9 +110,9 @@ def main(argv=None):
                          "like ttft_p95_ms / gap_p95_ms; breaches land "
                          "in the trace as slo_breach events")
     ap.add_argument("--profile", action="store_true",
-                    help="device-accurate step-phase timing "
-                         "(block_until_ready-bracketed) + paged-kernel "
-                         "cost/roofline profiles + recompile telemetry")
+                    help="paged-kernel cost/roofline profiles + "
+                         "recompile telemetry (step phases are program "
+                         "spans: take a jax.profiler trace)")
     ap.add_argument("--metrics-interval-steps", type=int, default=None,
                     metavar="N",
                     help="with --metrics-out: atomically re-write the "
@@ -183,7 +183,7 @@ def main(argv=None):
             engines, prefill_token_budget=args.prefill_token_budget,
             tracing=args.trace_out is not None,
             trace_buffer_events=args.trace_buffer_events,
-            slo_config=slo_config, profile=args.profile)
+            slo_config=slo_config)
         print(f"run config: arch={cfg.name} replicas={args.replicas} "
               f"max_slots={args.max_slots} max_seq_len={args.max_seq_len} "
               f"paged={args.paged} num_blocks={args.num_blocks} "
@@ -255,11 +255,6 @@ def main(argv=None):
                   f"active: {s['active'] or 'none'}")
     if args.profile:
         for rep in gateway.replicas:
-            ps = rep.scheduler.profiler.summary()
-            phases = "  ".join(
-                f"{p} p95 {ps[f'{p}_ms']['p95']:.2f}ms"
-                for p in ("admit", "prefill", "decode", "sample"))
-            print(f"profile [{rep.name}]: {ps['steps']} steps  {phases}")
             rs = rep.scheduler.engine.recompiles.summary()
             print(f"recompiles [{rep.name}]: {rs['compiles_total']} "
                   f"compilations, {rs['post_warm_recompiles']} post-warm, "

@@ -21,14 +21,14 @@ from repro.serving.kvcache import KVBlockPool, OutOfBlocks, PagedKVCache
 from repro.serving.metrics import (ServingMetrics, atomic_write_json,
                                    merge_summaries)
 from repro.serving.prefix_cache import PrefixCache, PrefixCacheStats
-from repro.serving.profiling import (RecompilationTracker, StepProfiler,
-                                     profile_kernel, profile_paged_kernels)
+from repro.serving.profiling import (RecompilationTracker, profile_kernel,
+                                     profile_paged_kernels)
 from repro.serving.scheduler import Scheduler
 from repro.serving.slo import (SLOConfig, SLOMonitor, SLOPolicy,
                                SlidingWindow, TenantStats,
                                merge_tenant_summaries,
                                merge_window_summaries)
-from repro.serving.tracing import (EVENT_KINDS, FAULT_EVENT_KINDS, Tracer,
-                                   export_chrome_trace, export_jsonl,
+from repro.serving.tracing import (EVENT_KINDS, FAULT_EVENT_KINDS, SPANS,
+                                   Tracer, export_chrome_trace, export_jsonl,
                                    merge_traces, to_chrome_trace,
                                    validate_event)

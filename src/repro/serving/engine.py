@@ -65,6 +65,7 @@ import numpy as np
 
 from repro.models import transformer as T
 from repro.serving.kvcache import PagedKVCache
+from repro.serving.tracing import Span, span
 
 
 @dataclass
@@ -470,25 +471,28 @@ class ServingEngine:
                 Bp = self.prefill_batch
                 while working and budget_left():
                     sel = working[:Bp]
-                    tables = np.full((Bp, self.kv.blocks_per_slot),
-                                     self.kv.trash_block, np.int32)
-                    toks = np.zeros((Bp, C), np.int32)
-                    starts = np.zeros(Bp, np.int32)
-                    qlens = np.zeros(Bp, np.int32)
-                    for r, cur in enumerate(sel):
-                        tables[r] = self.kv.table_row(cur.slot)
-                        ql = min(cur.remaining, C)
-                        toks[r, :ql] = cur.tokens[cur.pos:cur.pos + ql]
-                        starts[r] = cur.pos
-                        qlens[r] = ql
-                    self.recompiles.observe(
-                        "prefill_paged", (toks.shape, tables.shape),
-                        tracer=tr)
-                    logits, self.kv.cache = self._prefill_paged(
-                        self.params, jnp.asarray(toks), jnp.asarray(starts),
-                        jnp.asarray(qlens), self.kv.cache,
-                        jnp.asarray(tables))
-                    real = int(qlens.sum())
+                    with span(tr, "engine.prefill_round", rows=len(sel),
+                              executed_tokens=Bp * C) as sp:
+                        tables = np.full((Bp, self.kv.blocks_per_slot),
+                                         self.kv.trash_block, np.int32)
+                        toks = np.zeros((Bp, C), np.int32)
+                        starts = np.zeros(Bp, np.int32)
+                        qlens = np.zeros(Bp, np.int32)
+                        for r, cur in enumerate(sel):
+                            tables[r] = self.kv.table_row(cur.slot)
+                            ql = min(cur.remaining, C)
+                            toks[r, :ql] = cur.tokens[cur.pos:cur.pos + ql]
+                            starts[r] = cur.pos
+                            qlens[r] = ql
+                        self.recompiles.observe(
+                            "prefill_paged", (toks.shape, tables.shape),
+                            tracer=tr)
+                        logits, self.kv.cache = self._prefill_paged(
+                            self.params, jnp.asarray(toks),
+                            jnp.asarray(starts), jnp.asarray(qlens),
+                            self.kv.cache, jnp.asarray(tables))
+                        real = int(qlens.sum())
+                        sp.set(real_tokens=real)
                     # FLOPs proxy: every row of the compiled (Bp, C)
                     # program executes every round, dummy rows included
                     spent += Bp * C
@@ -509,17 +513,20 @@ class ServingEngine:
             else:
                 for cur in working:
                     while not cur.done and budget_left():
-                        if cur.dense_cache is None:
-                            self._materialize_dense(cur)
                         ql = min(cur.remaining, C)
-                        chunk = np.zeros(C, np.int32)
-                        chunk[:ql] = cur.tokens[cur.pos:cur.pos + ql]
-                        self.recompiles.observe(
-                            "prefill_chunk", (1, C), tracer=tr)
-                        cur.dense_cache, logits = self._prefill_chunk(
-                            self.params, jnp.asarray(chunk)[None],
-                            cur.dense_cache,
-                            jnp.full((1,), cur.pos, jnp.int32), cur.enc1)
+                        with span(tr, "engine.prefill_round", rows=1,
+                                  real_tokens=ql, executed_tokens=C):
+                            if cur.dense_cache is None:
+                                self._materialize_dense(cur)
+                            chunk = np.zeros(C, np.int32)
+                            chunk[:ql] = cur.tokens[cur.pos:cur.pos + ql]
+                            self.recompiles.observe(
+                                "prefill_chunk", (1, C), tracer=tr)
+                            cur.dense_cache, logits = self._prefill_chunk(
+                                self.params, jnp.asarray(chunk)[None],
+                                cur.dense_cache,
+                                jnp.full((1,), cur.pos, jnp.int32),
+                                cur.enc1)
                         li = (len(cur.tokens) - 1) - cur.pos
                         if 0 <= li < C:      # row's last real token here
                             cur.last_logits = logits[li]
@@ -576,24 +583,31 @@ class ServingEngine:
             # before any state mutation: a decode-site fault leaves the
             # cache untouched, so the scheduler can retry the same step
             self.fault_injector.on_engine_op("decode")
-        batch = {"tokens": jnp.asarray(tokens, jnp.int32)[:, None],
-                 "positions": jnp.asarray(positions, jnp.int32),
-                 "cache": self.kv.cache}
-        if self.paged:
-            # free slots' rows point at the trash block; their dummy
-            # writes and speculative gathers never touch live KV.  A
-            # mid-prefill slot's table maps real blocks already, so its
-            # row is masked to the trash block too — otherwise its
-            # dummy decode write at position 0 would corrupt KV the
-            # prefill just produced
-            batch["block_tables"] = self.kv.device_block_tables(
-                mask_slots=self._inflight)
-        if self._enc_pool is not None:
-            batch["encoder_output"] = self._enc_pool
-        self.recompiles.observe(
-            "decode_step", (np.shape(tokens), np.shape(positions)),
-            tracer=self.tracer)
-        logits, self.kv.cache = self._step(self.params, batch)
+        # live rows: slots holding a sequence that is not mid-prefill
+        live = len(self.kv.block_table) - len(self._inflight)
+        with span(self.tracer, "engine.decode", live_rows=live) as sp:
+            if Span.recording():
+                sp.set(ctx_tokens=sum(
+                    int(positions[s]) + 1 for s in self.kv.block_table
+                    if s not in self._inflight))
+            batch = {"tokens": jnp.asarray(tokens, jnp.int32)[:, None],
+                     "positions": jnp.asarray(positions, jnp.int32),
+                     "cache": self.kv.cache}
+            if self.paged:
+                # free slots' rows point at the trash block; their dummy
+                # writes and speculative gathers never touch live KV.  A
+                # mid-prefill slot's table maps real blocks already, so
+                # its row is masked to the trash block too — otherwise
+                # its dummy decode write at position 0 would corrupt KV
+                # the prefill just produced
+                batch["block_tables"] = self.kv.device_block_tables(
+                    mask_slots=self._inflight)
+            if self._enc_pool is not None:
+                batch["encoder_output"] = self._enc_pool
+            self.recompiles.observe(
+                "decode_step", (np.shape(tokens), np.shape(positions)),
+                tracer=self.tracer)
+            logits, self.kv.cache = self._step(self.params, batch)
         self.decode_steps += 1
         return logits[:, 0]                  # device-resident; no sync here
 
@@ -601,14 +615,15 @@ class ServingEngine:
                       greedy: np.ndarray) -> np.ndarray:
         """Per-row sampling: row i uses temps[i] / greedy[i].  Rows whose
         temperature is below 1e-4 (including exactly 0.0) sample greedily."""
-        self.key, sub = jax.random.split(self.key)
-        self.recompiles.observe("sample", np.shape(logits),
-                                tracer=self.tracer)
-        # deliberate: THE one host sync per step — the scheduler needs
-        # concrete token ids for EOS/retirement bookkeeping
-        return np.asarray(self._sample_vec(  # repro-lint: disable=RL001
-            sub, jnp.asarray(logits), jnp.asarray(temps, jnp.float32),
-            jnp.asarray(greedy)))
+        shape = np.shape(logits)
+        with span(self.tracer, "engine.sample", rows=shape[0]):
+            self.key, sub = jax.random.split(self.key)
+            self.recompiles.observe("sample", shape, tracer=self.tracer)
+            # deliberate: THE one host sync per step — the scheduler
+            # needs concrete token ids for EOS/retirement bookkeeping
+            return np.asarray(self._sample_vec(  # repro-lint: disable=RL001
+                sub, jnp.asarray(logits), jnp.asarray(temps, jnp.float32),
+                jnp.asarray(greedy)))
 
     def free_slot(self, slot: int) -> None:
         self.kv.free_slot(slot)

@@ -67,7 +67,7 @@ from repro.serving.health import (DEAD, HEALTHY, QUARANTINED, HealthConfig,
                                   HealthMonitor)
 from repro.serving.metrics import merge_summaries
 from repro.serving.scheduler import Scheduler
-from repro.serving.tracing import (Tracer, export_jsonl,
+from repro.serving.tracing import (Span, Tracer, export_jsonl,
                                    export_chrome_trace, merge_traces)
 
 
@@ -366,8 +366,12 @@ class ReplicaGateway:
     def step(self) -> bool:
         """One decode round on every routable replica with work, plus
         health bookkeeping, quarantine auto-rejoin, pending retries,
-        and the degradation-ladder update.  Returns True when anything
-        observable happened."""
+        and the degradation-ladder update, inside the ``gateway.step``
+        span.  Returns True when anything observable happened."""
+        with Span("gateway.step", replicas=len(self.replicas)):
+            return self._step_replicas()
+
+    def _step_replicas(self) -> bool:
         self._gstep += 1
         progressed = False
         for i, rep in enumerate(self.replicas):
@@ -531,7 +535,6 @@ class ReplicaGateway:
                 old.engine, tracer=old.tracer,
                 max_admissions_per_step=old.max_admissions_per_step,
                 prefill_token_budget=old.prefill_token_budget,
-                profile=old.profiler is not None,
                 fault_injector=inj)
             new._next_rid = old._next_rid
             new.done.update(old.done)  # finished outputs stay reachable
@@ -695,8 +698,6 @@ class ReplicaGateway:
             entry = {**s, "routed": rep.routed, "capsule": rep.capsule}
             if rep.scheduler.tracer.slo is not None:
                 entry["slo"] = rep.scheduler.tracer.slo.summary()
-            if rep.scheduler.profiler is not None:
-                entry["profile"] = rep.scheduler.profiler.summary()
             per[rep.name] = entry
         totals = merge_summaries(summaries)
         breaches = sum(p["slo"]["breaches"] for p in per.values()

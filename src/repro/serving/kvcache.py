@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import transformer as T
+from repro.serving.tracing import span
 
 
 class OutOfBlocks(RuntimeError):
@@ -419,9 +420,10 @@ class PagedKVCache:
         positions (block k of the list covers ``[k*bs, (k+1)*bs)``).
         Returns the updated cache tree."""
         assert self.prefix_pool is not None, "prefix store not enabled"
-        for k, bid in enumerate(blocks):
-            cache1 = self._load(cache1, self.prefix_store, jnp.int32(bid),
-                                jnp.int32(k))
+        with span(self.tracer, "kv.prefix_load", blocks=len(blocks)):
+            for k, bid in enumerate(blocks):
+                cache1 = self._load(cache1, self.prefix_store,
+                                    jnp.int32(bid), jnp.int32(k))
         return cache1
 
     def load_prefix_blocks_paged(self, slot: int,
@@ -435,12 +437,13 @@ class PagedKVCache:
         assert self.prefix_pool is not None, "prefix store not enabled"
         table = self.block_table[slot]
         assert len(blocks) <= len(table), (len(blocks), len(table))
-        for k, bid in enumerate(blocks):
-            # same block-to-block copy program as the save direction,
-            # with the trees swapped: cache[table[k]] <- prefix_store[bid]
-            self.cache = self._save_paged(
-                self.cache, self.prefix_store, jnp.int32(bid),
-                jnp.int32(table[k]))
+        with span(self.tracer, "kv.prefix_load", blocks=len(blocks)):
+            for k, bid in enumerate(blocks):
+                # same block-to-block copy program as the save direction,
+                # with the trees swapped: cache[table[k]] <- store[bid]
+                self.cache = self._save_paged(
+                    self.cache, self.prefix_store, jnp.int32(bid),
+                    jnp.int32(table[k]))
 
     def table_row(self, slot: int) -> np.ndarray:
         """The slot's padded block-table row (unbacked entries name the

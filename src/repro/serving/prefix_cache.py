@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.serving.kvcache import OutOfBlocks, PagedKVCache
+from repro.serving.tracing import span
 
 
 def _common_len(a: np.ndarray, b: np.ndarray) -> int:
@@ -271,20 +272,22 @@ class PrefixCache:
         if need <= 0:
             return 0
         blocks: List[int] = []
-        for k in range(need):
-            bi = bi_first + k
-            if k == 0 and overlap:
-                # COW: this branch gets its own block for the shared
-                # partial tail.  Ledger fork only — the save below fills
-                # the whole window from the slot (whose prefix positions
-                # are bit-identical to the shared block), so the physical
-                # copy of kv.fork_prefix_block would be dead work here.
-                bid = self.pool.fork(parent.blocks[-1])
-                self.stats.forked_blocks += 1
-                self.kv.save_prefix_block(slot, bi * bs, into=bid)
-            else:
-                bid = self.kv.save_prefix_block(slot, bi * bs)
-            blocks.append(bid)
+        with span(self.tracer, "kv.prefix_save", blocks=need):
+            for k in range(need):
+                bi = bi_first + k
+                if k == 0 and overlap:
+                    # COW: this branch gets its own block for the shared
+                    # partial tail.  Ledger fork only — the save below
+                    # fills the whole window from the slot (whose prefix
+                    # positions are bit-identical to the shared block),
+                    # so the physical copy of kv.fork_prefix_block would
+                    # be dead work here.
+                    bid = self.pool.fork(parent.blocks[-1])
+                    self.stats.forked_blocks += 1
+                    self.kv.save_prefix_block(slot, bi * bs, into=bid)
+                else:
+                    bid = self.kv.save_prefix_block(slot, bi * bs)
+                blocks.append(bid)
         covered_end = min((bi_first + need) * bs, total)
         leaf = _Node(pos, tokens[pos:covered_end], blocks, parent)
         leaf.last_used = self._tick()

@@ -1,14 +1,8 @@
 """In-capsule performance profiling for the serving stack.
 
-The capsule cannot run an external profiler daemon, so the three things
-an operator needs to localize a slowdown are built in:
-
-* :class:`StepProfiler` — device-accurate step-phase timing.  The
-  scheduler's phase timestamps normally measure *dispatch* (JAX is
-  async); with profiling on, the scheduler brackets each phase with
-  ``block_until_ready`` so the deltas are wall time the device actually
-  spent in admit / prefill / decode / sample.  Windows are bounded
-  (:class:`~repro.serving.slo.SlidingWindow`).
+The capsule cannot run an external profiler daemon, so what an operator
+needs to localize a slowdown beyond the profiler's own trace (step
+phases are program spans, ``tracing.SPANS``) is built in:
 
 * :func:`profile_kernel` / :func:`profile_paged_kernels` — per-kernel
   profiles for the paged attention kernels at serving shapes: compiled
@@ -35,33 +29,6 @@ from __future__ import annotations
 
 import time
 from typing import Callable, Dict, List, Optional, Tuple
-
-from repro.serving.slo import SlidingWindow
-
-PHASES = ("admit", "prefill", "decode", "sample")
-
-
-class StepProfiler:
-    """Bounded per-phase timing windows, fed by ``Scheduler.step()``
-    when the scheduler is constructed with ``profile=True``."""
-
-    def __init__(self, window: int = 512):
-        self.phases: Dict[str, SlidingWindow] = {
-            p: SlidingWindow(window) for p in PHASES}
-        self.steps = 0
-
-    def record_step(self, admit_s: float, prefill_s: float,
-                    decode_s: float, sample_s: float) -> None:
-        self.steps += 1
-        for name, dur in zip(PHASES, (admit_s, prefill_s,
-                                      decode_s, sample_s)):
-            self.phases[name].add(dur * 1e3)
-
-    def summary(self) -> Dict[str, object]:
-        out: Dict[str, object] = {"steps": self.steps}
-        for name, win in self.phases.items():
-            out[f"{name}_ms"] = win.summary()
-        return out
 
 
 def profile_kernel(fn: Callable, *args, name: str, reps: int = 5,

@@ -77,7 +77,6 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -112,7 +111,6 @@ class Scheduler:
                  max_admissions_per_step: Optional[int] = None,
                  prefill_token_budget: Optional[int] = None,
                  tracer: Optional[Tracer] = None,
-                 profile: bool = False,
                  fault_injector=None):
         self.engine = engine
         self.max_slots = engine.max_slots
@@ -150,15 +148,6 @@ class Scheduler:
         engine.kv.tracer = tracer
         if engine.prefix_cache is not None:
             engine.prefix_cache.tracer = tracer
-        # step-phase profiling: with profile=True each phase is
-        # bracketed by block_until_ready so the t0..t4 deltas measure
-        # device time, not dispatch time (JAX is async); the windows
-        # live on self.profiler.  Off by default — the sync points
-        # serialize the pipeline and cost real throughput.
-        self.profiler = None
-        if profile:
-            from repro.serving.profiling import StepProfiler
-            self.profiler = StepProfiler()
         self.queue: deque = deque()
         self.active: Dict[int, _ReqState] = {}          # slot -> state
         self.prefilling: Dict[int, _ReqState] = {}      # slot -> mid-prefill
@@ -519,27 +508,23 @@ class Scheduler:
                     if victim is None:
                         break              # st itself deferred; move on
 
-    def _close_step(self, tr, decoded: bool, admitted: int, completed: int,
-                    executed: int, t0: float, t1: float, t2: float,
-                    t3: float) -> None:
-        """Emit the per-step engine-timeline event (phase breakdown +
-        gauges snapshot) and sample the step gauges into the metrics
-        when a decode round actually ran (the pre-tracing semantics)."""
+    def _close_step(self, decoded: bool, admitted: int, completed: int,
+                    executed: int) -> None:
+        """Emit the per-step engine-timeline event (the step's span
+        durations + gauges snapshot) and sample the step gauges into the
+        metrics when a decode round actually ran (the pre-tracing
+        semantics)."""
         kv = self.engine.kv
-        t4 = tr.clock()
+        tr = self.tracer
         tr.engine_step(
             decoded=decoded, queue_depth=len(self.queue),
             active=len(self.active), max_slots=self.max_slots,
             admitted=admitted, completed=completed,
             prefill_executed=executed, budget=self.prefill_token_budget,
-            dur_admit_s=t1 - t0, dur_prefill_s=t2 - t1,
-            dur_decode_s=t3 - t2, dur_sample_s=t4 - t3,
             free_blocks=kv.pool.available, free_slots=kv.free_slot_count,
             inflight=len(self.prefilling),
             prefix_pins=(kv.prefix_pool.in_use
                          if kv.prefix_pool is not None else 0))
-        if self.profiler is not None:
-            self.profiler.record_step(t1 - t0, t2 - t1, t3 - t2, t4 - t3)
         tr.check_slo()
 
     def step(self) -> bool:
@@ -548,10 +533,11 @@ class Scheduler:
         across in-flight admissions, then decode one token for every
         live sequence.  Returns False when there was nothing to do.
 
-        Every call emits one ``engine_step`` trace event with the phase
-        durations (admission / prefill-advance / decode dispatch /
-        sample+retire) and a gauges snapshot, so a stalled request can
-        be read against what the engine was actually doing that step."""
+        The step runs inside the ``scheduler.step`` span and each phase
+        inside its own (``tracing.SPANS``); every call that returns
+        emits one ``engine_step`` trace event with the phase durations
+        and a gauges snapshot, so a stalled request can be read against
+        what the engine was actually doing that step."""
         fi = self.fault_injector
         if fi is not None and fi.on_step() == "stall":
             # injected wedge: claim liveness, do nothing.  This is the
@@ -559,22 +545,23 @@ class Scheduler:
             # progress checks are satisfied, only the gateway's
             # progress-signature watchdog can tell
             return True
+        with self.tracer.span("scheduler.step"):
+            ret, decoded, admitted, completed, executed = self._run_step()
+        self._close_step(decoded, admitted, completed, executed)
+        return ret
+
+    def _run_step(self):
+        """The body of :meth:`step`.  Returns ``(ret, decoded, admitted,
+        completed, executed)``: step's return value, whether a decode
+        round ran, requests admitted, prefills completed, and prefill
+        token positions executed."""
         tr = self.tracer
-        prof = self.profiler
-        t0 = tr.clock()
-        admitted = self._admit()
-        if prof is not None:                 # device-accurate phase edges
-            # deliberate: only when step profiling is armed, so phase
-            # walls measure device time, not dispatch time
-            jax.block_until_ready(self.engine.kv.cache)  # repro-lint: disable=RL001
-        t1 = tr.clock()
+        with tr.span("scheduler.admit", queue_depth=len(self.queue)) as sp:
+            admitted = self._admit()
+            sp.set(admitted=admitted)
         exec0 = self.engine.prefill_tokens_executed
         completed = self._advance_prefill()
-        if prof is not None:
-            # deliberate: profiler-gated phase edge (see above)
-            jax.block_until_ready(self.engine.kv.cache)  # repro-lint: disable=RL001
         executed = self.engine.prefill_tokens_executed - exec0
-        t2 = tr.clock()
         if not self.active:
             if self.prefilling:
                 ret = True                 # prefill progressing; no decode yet
@@ -589,14 +576,14 @@ class Scheduler:
                 # everything admitted this step retired at its first
                 # token (or the admission cap paused the queue)
                 ret = bool(self.queue) or admitted > 0 or completed > 0
-            self._close_step(tr, False, admitted, completed, executed,
-                             t0, t1, t2, t2)
-            return ret
-        self._grow_or_preempt()
+            return ret, False, admitted, completed, executed
+        with tr.span("scheduler.grow") as sp:
+            preempted = self.preemptions
+            self._grow_or_preempt()
+            sp.set(preempted=self.preemptions - preempted)
         if not self.active:                # everything deferred; retry
-            self._close_step(tr, False, admitted, completed, executed,
-                             t0, t1, t2, t2)
-            return bool(self.queue or self.prefilling)
+            return (bool(self.queue or self.prefilling), False, admitted,
+                    completed, executed)
         S = self.max_slots
         tokens = np.zeros(S, np.int32)
         positions = np.zeros(S, np.int32)
@@ -608,25 +595,22 @@ class Scheduler:
             temps[slot] = st.request.params.temperature
             greedy[slot] = st.request.params.greedy
         logits = self.engine.decode_once(tokens, positions)
-        if prof is not None:
-            # deliberate: profiler-gated phase edge (see _admit edge)
-            jax.block_until_ready(logits)  # repro-lint: disable=RL001
-        t3 = tr.clock()
         toks = self.engine.sample_tokens(logits, temps, greedy)
-        # per-tenant inter-token gaps: record before retirement pops the
-        # rows' last-token timestamps
-        tr.decode_tokens([st.rid for st in self.active.values()])
-        for slot in list(self.active):
-            st = self.active[slot]
-            st.pos += 1
-            tok = int(toks[slot])
-            st.emitted.append(tok)
-            if tr.enabled:
-                tr.decode(st.rid, st.pos - 1, tok)
-            self._maybe_retire(st, tok)
-        self._close_step(tr, True, admitted, completed, executed,
-                         t0, t1, t2, t3)
-        return True
+        with tr.span("scheduler.retire") as sp:
+            # per-tenant inter-token gaps: record before retirement pops
+            # the rows' last-token timestamps
+            tr.decode_tokens([st.rid for st in self.active.values()])
+            retired = len(self.done)
+            for slot in list(self.active):
+                st = self.active[slot]
+                st.pos += 1
+                tok = int(toks[slot])
+                st.emitted.append(tok)
+                if tr.enabled:
+                    tr.decode(st.rid, st.pos - 1, tok)
+                self._maybe_retire(st, tok)
+            sp.set(retired=len(self.done) - retired)
+        return True, True, admitted, completed, executed
 
     def run(self) -> None:
         """Run until the queue and all slots are empty."""

@@ -11,7 +11,7 @@ stall for 40 steps" — was it an ``OutOfBlocks`` admission stall, a
 recompute preemption, a cold prefix probe, or a replica whose prefill
 budget sat idle.
 
-Three layers:
+Four layers:
 
 * :class:`Tracer` — one per scheduler/replica.  Typed events (kinds in
   :data:`EVENT_KINDS`) appended to a bounded ring buffer
@@ -34,10 +34,21 @@ Three layers:
   span reads as one request even as it migrates across slots.
 
 * **Engine step timeline** — one ``engine_step`` event per
-  ``Scheduler.step()`` with the phase breakdown (admission /
-  prefill-advance / decode dispatch / sample+retire, in seconds) and a
-  gauges snapshot: free KV blocks, free slots, pinned prefix blocks,
-  in-flight prefill cursors, queue depth, live sequences.
+  ``Scheduler.step()`` with the time each phase of the step took (the
+  ``dur_*_s`` fields :data:`SPANS` names) and a gauges snapshot: free KV
+  blocks, free slots, pinned prefix blocks, in-flight prefill cursors,
+  queue depth, live sequences.
+
+* **Program spans** — every phase of a step runs inside a :class:`Span`
+  (names in :data:`SPANS`): a ``jax.profiler.TraceAnnotation`` that the
+  profiler records whenever a trace is being taken, whether or not the
+  ring is enabled, with ``step`` (the tracer's step id) and the phase's
+  counts as stats.  With the ring enabled the same span boundaries time
+  the phase for the ``engine_step`` event, so each phase is measured
+  once, in one place.  The profiler's trace and the ring keep different
+  clocks; they join on ``step``: each ``scheduler.step`` span and the
+  ``engine_step`` event of the same step close together, so one offset
+  per run maps the ring's ``ts`` onto the profiler's time axis.
 
 Exporters (files only, per the no-external-systems constraint):
 
@@ -66,6 +77,8 @@ import time
 from collections import deque
 from pathlib import Path
 from typing import Dict, Iterable, List, Mapping, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from repro.serving.metrics import ServingMetrics
 from repro.serving.slo import SLOMonitor
@@ -114,6 +127,24 @@ EVENT_KINDS = frozenset({
     "recompile",         # a jitted program saw a novel shape signature
 }) | FAULT_EVENT_KINDS
 
+# The program's phase spans, prefixed by their layer, each with the
+# ``engine_step`` field an enabled tracer sums its durations into
+# (``gateway.step`` covers every replica, so no replica's ring carries
+# it).  The profiler records all of them whenever it runs; perfbench's
+# span readers and ``serving/README.md`` read them by these names.
+SPANS = {
+    "gateway.step": None,                  # replicas
+    "scheduler.step": "dur_step_s",        # step: the join key
+    "scheduler.admit": "dur_admit_s",      # admitted, queue_depth
+    "scheduler.grow": "dur_grow_s",        # preempted
+    "scheduler.retire": "dur_retire_s",    # retired
+    "engine.prefill_round": "dur_prefill_s",  # rows, real/executed_tokens
+    "engine.decode": "dur_decode_s",       # live_rows, ctx_tokens
+    "engine.sample": "dur_sample_s",       # rows
+    "kv.prefix_load": "dur_prefix_load_s",  # blocks
+    "kv.prefix_save": "dur_prefix_save_s",  # blocks
+}
+
 # kinds that must carry a request id (the rest are step-scoped;
 # prefill_advance / block events resolve rids through slot bindings and
 # legitimately fall back to step scope when the engine is driven raw)
@@ -124,6 +155,53 @@ _RID_KINDS = frozenset({
 })
 
 DEFAULT_BUFFER_EVENTS = 65536
+
+
+class Span(TraceAnnotation):
+    """One program span: a profiler annotation named ``name`` with
+    ``stats`` (ints the caller already holds), recorded only while a
+    profiler trace is being taken.  Open it through :meth:`Tracer.span`
+    or :func:`span`."""
+
+    @staticmethod
+    def recording() -> bool:
+        """True while a profiler trace is being taken."""
+        return TraceAnnotation.is_enabled()
+
+    def set(self, **stats) -> None:
+        """Stats known only at the span's end (a count of what it did)."""
+        if TraceAnnotation.is_enabled():
+            self.set_metadata(**stats)
+
+
+class _RingSpan(Span):
+    """A span that also adds its duration, on the tracer's clock, to the
+    step's phase time of an enabled :class:`Tracer`."""
+
+    def __init__(self, ring: "Tracer", name: str, **stats):
+        super().__init__(name, **stats)
+        self._ring = ring
+        self._name = name
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_RingSpan":
+        super().__enter__()
+        self._t0 = self._ring.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        ring = self._ring
+        ph = ring._phase_s
+        ph[self._name] = ph.get(self._name, 0.0) + ring.clock() - self._t0
+        super().__exit__(*exc)
+
+
+def span(tracer: Optional["Tracer"], name: str, **stats) -> Span:
+    """``tracer.span(name, **stats)``, or a bare profiler annotation for
+    a component driven without a scheduler (``tracer`` None)."""
+    if tracer is None:
+        return Span(name, **stats)
+    return tracer.span(name, **stats)
 
 
 class Tracer:
@@ -156,6 +234,7 @@ class Tracer:
         self.emitted_events = 0            # incl. any the ring dropped
         self.current_step = 0              # stamped on every event
         self._slot_rid: Dict[int, int] = {}   # engine-side rid resolution
+        self._phase_s: Dict[str, float] = {}  # span name -> s this step
 
     @property
     def dropped_events(self) -> int:
@@ -171,6 +250,17 @@ class Tracer:
             ev.update(data)
         self.events.append(ev)
         self.emitted_events += 1
+
+    def span(self, name: str, **stats) -> Span:
+        """Open program span ``name`` (one of :data:`SPANS`) with stats
+        ``step`` (the current step id, the key that joins the profiler's
+        trace to this ring) and ``stats``.  With the ring enabled its
+        duration also goes into this step's ``engine_step`` event."""
+        if not self.enabled:
+            return Span(name, step=self.current_step, **stats)
+        if name == "scheduler.step":
+            self._phase_s.clear()      # a step that raised left partials
+        return _RingSpan(self, name, step=self.current_step, **stats)
 
     def bind_slot(self, slot: int, rid: int) -> None:
         """Register slot -> rid so engine/kv events resolve to a span."""
@@ -402,26 +492,25 @@ class Tracer:
     def engine_step(self, *, decoded: bool, queue_depth: int, active: int,
                     max_slots: int, admitted: int, completed: int,
                     prefill_executed: int, budget: Optional[int],
-                    dur_admit_s: float, dur_prefill_s: float,
-                    dur_decode_s: float, dur_sample_s: float,
                     free_blocks: int, free_slots: int, inflight: int,
                     prefix_pins: int) -> None:
         """Close one scheduler step: gauge sampling (decode steps only —
-        the pre-tracing metrics semantics) plus the timeline event."""
+        the pre-tracing metrics semantics) plus the timeline event, whose
+        ``dur_*_s`` fields are the step's span durations (0.0 for a phase
+        that did not run)."""
         if decoded:
             self.metrics.sample_gauges(queue_depth, active, max_slots)
         if self.enabled:
+            ph = self._phase_s
+            durs = {f: ph.get(name, 0.0) for name, f in SPANS.items() if f}
+            ph.clear()
             self._emit("engine_step", decoded=decoded,
                        queue_depth=queue_depth, active=active,
                        admitted=admitted, completed=completed,
                        prefill_executed=prefill_executed,
                        budget=budget if budget is not None else 0,
-                       dur_admit_s=dur_admit_s,
-                       dur_prefill_s=dur_prefill_s,
-                       dur_decode_s=dur_decode_s,
-                       dur_sample_s=dur_sample_s,
                        free_blocks=free_blocks, free_slots=free_slots,
-                       inflight=inflight, prefix_pins=prefix_pins)
+                       inflight=inflight, prefix_pins=prefix_pins, **durs)
         self.current_step += 1
 
     # -- export --------------------------------------------------------------
@@ -472,6 +561,15 @@ def _span_bounds(evs: List[dict]) -> Dict[int, List[dict]]:
         if rid >= 0:
             by_rid.setdefault(rid, []).append(ev)
     return by_rid
+
+
+# engine-thread slices of one step: the step, then its top-level phases
+# in the order ``Scheduler.step`` runs them (``kv.prefix_load`` runs
+# inside admission or a dense prefill round, so it has no place here)
+_CHROME_PHASES = ("scheduler.step", "scheduler.admit",
+                  "engine.prefill_round", "kv.prefix_save",
+                  "scheduler.grow", "engine.decode", "engine.sample",
+                  "scheduler.retire")
 
 
 def to_chrome_trace(events_by_replica: Mapping[str, Sequence[Mapping]]
@@ -531,19 +629,21 @@ def to_chrome_trace(events_by_replica: Mapping[str, Sequence[Mapping]]
                 continue
             if ev["kind"] != "engine_step":
                 continue
-            end = ev["ts"]
-            phases = [("sample", ev.get("dur_sample_s", 0.0)),
-                      ("decode", ev.get("dur_decode_s", 0.0)),
-                      ("prefill", ev.get("dur_prefill_s", 0.0)),
-                      ("admit", ev.get("dur_admit_s", 0.0))]
-            for name, dur in phases:       # walk backwards from step end
+            # the step's slice ends at the event; its phases are laid end
+            # to end from the step's start in the order a step runs them.
+            # The ring keeps each phase's time, not its offset: exact
+            # placement is in the profiler's trace, joined on ``step``
+            t = ev["ts"] - ev.get("dur_step_s", 0.0)
+            for name in _CHROME_PHASES:
+                dur = ev.get(SPANS[name], 0.0)
                 if dur <= 0.0:
                     continue
                 out.append({"ph": "X", "cat": "engine", "name": name,
                             "pid": pid, "tid": 1,
-                            "ts": us(end - dur), "dur": dur * 1e6,
+                            "ts": us(t), "dur": dur * 1e6,
                             "args": {"step": ev["step"]}})
-                end -= dur
+                if name != "scheduler.step":
+                    t += dur
             out.append({"ph": "C", "pid": pid, "name": "free_blocks",
                         "ts": us(ev["ts"]),
                         "args": {"free_blocks": ev.get("free_blocks", 0)}})

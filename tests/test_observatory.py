@@ -1,14 +1,14 @@
 """Serving observatory: per-tenant SLO monitoring, bounded percentile
-windows, step/kernel profiling, recompilation telemetry, and the
+windows, kernel profiling, recompilation telemetry, and the
 trace_report SLO/profile sections.
 
 Unit layers (SlidingWindow / TenantStats / SLOMonitor /
-RecompilationTracker / StepProfiler) run against injected clocks; the
-end-to-end tests drive real scheduler runs on the smoke model and pin
-the contracts the benchmark relies on: tenant labels thread
-submit -> scheduler -> summary -> merge, breach transitions land in the
-trace as valid events, profiling is inert on outputs, and steady-state
-serving never recompiles post-warm while injected shape churn does.
+RecompilationTracker) run against injected clocks; the end-to-end tests
+drive real scheduler runs on the smoke model and pin the contracts the
+benchmark relies on: tenant labels thread submit -> scheduler ->
+summary -> merge, breach transitions land in the trace as valid events,
+and steady-state serving never recompiles post-warm while injected
+shape churn does.
 """
 import json
 
@@ -16,10 +16,10 @@ import jax
 import numpy as np
 import pytest
 
-from repro.serving import (RecompilationTracker, Request, SamplingParams,
-                           Scheduler, ServingEngine, ServingMetrics,
-                           SLOConfig, SLOMonitor, SLOPolicy, SlidingWindow,
-                           StepProfiler, TenantStats, Tracer,
+from repro.serving import (SPANS, RecompilationTracker, Request,
+                           SamplingParams, Scheduler, ServingEngine,
+                           ServingMetrics, SLOConfig, SLOMonitor, SLOPolicy,
+                           SlidingWindow, TenantStats, Tracer,
                            atomic_write_json, merge_summaries,
                            merge_window_summaries, validate_event)
 from repro.serving.metrics import _pct
@@ -394,49 +394,8 @@ def test_steady_state_zero_postwarm_then_injected_churn_warns(qwen):
 
 
 # ---------------------------------------------------------------------------
-# step profiler
+# kernel profiler
 # ---------------------------------------------------------------------------
-
-def test_step_profiler_windows():
-    prof = StepProfiler(window=4)
-    for i in range(10):
-        prof.record_step(0.001, 0.002 * i, 0.003, 0.0)
-    s = prof.summary()
-    assert s["steps"] == 10
-    assert s["admit_ms"]["count"] == 10
-    assert s["admit_ms"]["p50"] == pytest.approx(1.0)
-    assert s["prefill_ms"]["max"] == pytest.approx(18.0)
-    assert s["sample_ms"]["p95"] == 0.0
-
-
-def test_profiling_populates_phases_and_is_inert_on_outputs(qwen):
-    cfg, _ = qwen
-    rng = np.random.default_rng(12)
-    prompts = [_prompt(rng, cfg, n) for n in (7, 13)]
-    eng = _engine(qwen, paged=True)
-
-    def serve(profile):
-        sched = Scheduler(eng, tracer=Tracer(), profile=profile)
-        rids = [sched.submit(Request(p, SamplingParams(max_new_tokens=3,
-                                                       greedy=True)))
-                for p in prompts]
-        sched.run()
-        return [sched.output(r) for r in rids], sched.profiler
-
-    plain_out, none_prof = serve(False)
-    prof_out, prof = serve(True)
-    assert none_prof is None
-    for a, b in zip(plain_out, prof_out):
-        np.testing.assert_array_equal(a, b)    # profiling is inert
-    s = prof.summary()
-    assert s["steps"] > 0
-    for phase in ("admit", "prefill", "decode", "sample"):
-        st = s[f"{phase}_ms"]
-        assert st["count"] == s["steps"]
-        assert st["max"] >= 0.0
-    # the decode phase of a real run takes measurable device time
-    assert s["decode_ms"]["max"] > 0.0
-
 
 def test_profile_paged_kernels_structure(qwen):
     from repro.serving import profile_paged_kernels
@@ -468,8 +427,7 @@ def test_observatory_end_to_end_and_trace_report(qwen, tmp_path, capsys):
         "default": {"ttft_p95_ms": 1e9},
         "tenants": {"gold": {"ttft_p95_ms": 1e-6, "min_samples": 1}}})
     tracer = Tracer(enabled=True, slo=SLOMonitor(slo))
-    sched = Scheduler(_engine(qwen, paged=True), tracer=tracer,
-                      profile=True)
+    sched = Scheduler(_engine(qwen, paged=True), tracer=tracer)
     for i in range(4):
         sched.submit(Request(
             _prompt(rng, cfg, int(rng.integers(5, 20))),
@@ -501,8 +459,9 @@ def test_observatory_end_to_end_and_trace_report(qwen, tmp_path, capsys):
     assert set(data["slo"]["tenants"]) == {"gold", "basic"}
     assert data["slo"]["breaches"]
     assert all(b["tenant"] == "gold" for b in data["slo"]["breaches"])
-    assert set(data["profile"]["phases"]) == {"admit", "prefill",
-                                              "decode", "sample"}
+    assert set(data["profile"]["phases"]) == {
+        name for name, field in SPANS.items() if field}
+    assert data["profile"]["phases"]["engine.decode"]["max"] > 0.0
     assert data["requests"]["requests"]
     capsys.readouterr()                        # drain the report text
 
@@ -529,7 +488,8 @@ def test_trace_report_empty_sections_warn_and_fail_validate(tmp_path,
 
 def test_serve_launcher_observatory_flags(qwen, tmp_path, capsys):
     """The CLI path (satellite b): --tenant/--slo-config/--profile/
-    --metrics-out with periodic atomic flushes."""
+    --metrics-out with periodic atomic flushes.  --profile prints the
+    recompile and kernel lines; step phases are program spans."""
     from repro.launch import serve
     slo_path = tmp_path / "slo.json"
     slo_path.write_text(json.dumps(
@@ -544,7 +504,7 @@ def test_serve_launcher_observatory_flags(qwen, tmp_path, capsys):
     out = capsys.readouterr().out
     assert "tenant a:" in out and "tenant b:" in out
     assert "SLO [replica0]:" in out
-    assert "profile [replica0]:" in out and "recompiles [replica0]:" in out
+    assert "recompiles [replica0]:" in out and "profile [" not in out
     totals = json.loads(metrics.read_text())
     assert totals["requests_completed"] == 3
     assert set(totals["tenants"]) == {"a", "b"}

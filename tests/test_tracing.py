@@ -1,5 +1,6 @@
 """Request-lifecycle tracing: event schema, ring buffer, preemption
-observability, Chrome/JSONL exporters, gateway trace merge, and the
+observability, Chrome/JSONL exporters, gateway trace merge, program
+spans in the profiler's trace joined to the ring on ``step``, and the
 ``merge_summaries`` edge-case contract.
 
 Also hosts the executable form of the ROADMAP near-tie caveat: a
@@ -7,13 +8,15 @@ slow-marked sweep asserting that any paged-vs-dense greedy divergence
 happens only at near-tie top-2 logits (page-wise online-softmax
 summation order), never at a decisive argmax.
 """
+import collections
+import glob
 import json
 
 import jax
 import numpy as np
 import pytest
 
-from repro.serving import (EVENT_KINDS, ReplicaGateway, Request,
+from repro.serving import (EVENT_KINDS, SPANS, ReplicaGateway, Request,
                            SamplingParams, Scheduler, ServingEngine,
                            Tracer, export_chrome_trace, merge_summaries,
                            merge_traces, to_chrome_trace, validate_event)
@@ -98,9 +101,7 @@ def test_event_schema_and_validator():
     tr.block_alloc(0, 2, 10)           # resolves rid through the binding
     tr.engine_step(decoded=False, queue_depth=1, active=0, max_slots=2,
                    admitted=0, completed=0, prefill_executed=0, budget=None,
-                   dur_admit_s=0.0, dur_prefill_s=0.0, dur_decode_s=0.0,
-                   dur_sample_s=0.0, free_blocks=10, free_slots=2,
-                   inflight=0, prefix_pins=0)
+                   free_blocks=10, free_slots=2, inflight=0, prefix_pins=0)
     evs = tr.snapshot()
     assert [e["kind"] for e in evs] == ["submit", "block_alloc",
                                        "engine_step"]
@@ -160,12 +161,14 @@ def test_traced_run_covers_lifecycle_and_validates(qwen, tmp_path):
     assert [e["step"] for e in steps] == list(range(len(steps)))
     assert sum(1 for e in steps if e["decoded"]) == \
         sched.metrics.decode_steps
-    # phase durations are sane: all non-negative, and on decoded steps
-    # the decode dispatch took measurable time
+    # every span's phase field is on every step, non-negative, and a
+    # decoded step spent time in its decode span
     for e in steps:
-        for k in ("dur_admit_s", "dur_prefill_s", "dur_decode_s",
-                  "dur_sample_s"):
+        for k in filter(None, SPANS.values()):
             assert e[k] >= 0.0
+        if e["decoded"]:
+            assert e["dur_decode_s"] > 0.0
+        assert e["dur_step_s"] >= e["dur_admit_s"] + e["dur_decode_s"]
     # JSONL round-trips through the file exporter
     path = tracer.export_jsonl(tmp_path / "run.jsonl")
     lines = [json.loads(l) for l in path.read_text().splitlines()]
@@ -306,6 +309,130 @@ def test_tracing_is_inert_on_outputs(qwen):
 
     for a, b in zip(serve(None), serve(Tracer(enabled=True))):
         np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# program spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under the profiler (Python tracer off) and return the
+    program spans it recorded: ``(name, start_s, end_s, stats)``."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    spans = [(e.name, e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9,
+              dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name in SPANS]
+    return out, spans
+
+
+def test_program_spans_join_the_ring_on_step(qwen, tmp_path):
+    """Every span lands in the profiler's trace with its stats; engine
+    and KV spans nest in a scheduler step; each step span's ``step``
+    names exactly one ``engine_step`` event, one offset maps the ring's
+    clock onto the profiler's for every step, and the ring's phase
+    durations are the spans' own."""
+    cfg, _ = qwen
+    rng = np.random.default_rng(7)
+    shared = _prompt(rng, cfg, 16)
+    gw = ReplicaGateway.from_engines(
+        [_engine(qwen, paged=True, prefix_cache_blocks=16)], tracing=True)
+
+    def serve():
+        # the second prompt resumes from the prefix the first one saved
+        for n in (5, 9):
+            gw.submit(Request(np.concatenate([shared, _prompt(rng, cfg, n)]),
+                              SamplingParams(max_new_tokens=3, greedy=True)))
+            while gw.has_work:
+                gw.step()
+
+    _, spans = _profile(tmp_path, serve)
+    assert {n for n, *_ in spans} == set(SPANS)
+    steps = [sp for sp in spans if sp[0] == "scheduler.step"]
+    for name, s, e, stats in spans:
+        assert "step" in stats or name == "gateway.step"
+        if name.startswith(("engine.", "kv.")):
+            assert any(s0 <= s and e <= e0 for _, s0, e0, _ in steps), name
+    assert all(st["replicas"] == 1 for n, _, _, st in spans
+               if n == "gateway.step")
+    ring = [ev for ev in gw.tracers[0].snapshot()
+            if ev["kind"] == "engine_step"]
+    by_step = collections.Counter(ev["step"] for ev in ring)
+    assert len(steps) == len(ring)
+    assert all(by_step[st["step"]] == 1 for _, _, _, st in steps)
+    ring = {ev["step"]: ev for ev in ring}
+    offsets = [ring[st["step"]]["ts"] - ring[st["step"]]["dur_step_s"] - s
+               for _, s, _, st in steps]
+    assert max(offsets) - min(offsets) < 1e-3
+    per_step = collections.defaultdict(float)
+    for name, s, e, stats in spans:
+        if SPANS[name]:
+            per_step[stats["step"], SPANS[name]] += e - s
+    for step, ev in ring.items():
+        for field in filter(None, SPANS.values()):
+            assert ev[field] == pytest.approx(per_step[step, field],
+                                              abs=5e-4), (step, field)
+
+
+def test_tokens_identical_with_profiler_on_and_off(qwen, tmp_path):
+    """Spans only annotate: greedy outputs are bit-identical whether or
+    not a profiler trace is being taken, and the profiled run recorded
+    its spans."""
+    cfg, _ = qwen
+    rng = np.random.default_rng(12)
+    prompts = [_prompt(rng, cfg, n) for n in (7, 13)]
+    eng = _engine(qwen, paged=True)
+
+    def serve():
+        sched = Scheduler(eng, tracer=Tracer())
+        rids = [sched.submit(Request(p, SamplingParams(max_new_tokens=3,
+                                                       greedy=True)))
+                for p in prompts]
+        sched.run()
+        return [sched.output(r) for r in rids]
+
+    plain = serve()
+    profiled, spans = _profile(tmp_path, serve)
+    for a, b in zip(plain, profiled):
+        np.testing.assert_array_equal(a, b)
+    assert {"scheduler.step", "engine.decode", "engine.sample"} <= {
+        n for n, *_ in spans}
+
+
+def test_span_without_ring_keeps_no_phase_time():
+    """A disabled tracer's spans only annotate; an enabled one sums each
+    span's time into the step's ``engine_step`` fields."""
+    t = [0.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+
+    off, on = Tracer(clock=clock), Tracer(enabled=True, clock=clock)
+    for tr in (off, on):
+        with tr.span("scheduler.step"):
+            with tr.span("engine.decode", live_rows=2) as sp:
+                sp.set(ctx_tokens=9)
+            with tr.span("engine.decode", live_rows=2):
+                pass
+        tr.engine_step(decoded=True, queue_depth=0, active=2, max_slots=2,
+                       admitted=0, completed=0, prefill_executed=0,
+                       budget=None, free_blocks=0, free_slots=0, inflight=0,
+                       prefix_pins=0)
+    assert off._phase_s == {} and not off.events
+    [ev] = on.snapshot()
+    assert ev["dur_decode_s"] == 2.0          # two spans of one tick each
+    assert ev["dur_step_s"] == 5.0
+    assert ev["dur_admit_s"] == 0.0 and on._phase_s == {}
 
 
 # ---------------------------------------------------------------------------
