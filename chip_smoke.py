@@ -193,7 +193,7 @@ def kernel_programs(engine):
     decode = engine._step.lower(engine.params, {
         "tokens": jnp.asarray(slots, jnp.int32)[:, None],
         "positions": jnp.asarray(slots, jnp.int32),
-        "cache": kv.cache, "block_tables": kv.device_block_tables()})
+        "block_tables": kv.device_block_tables()}, kv.cache)
     return {"prefill_paged": prefill.compile().as_text(),
             "decode_step": decode.compile().as_text()}
 

@@ -65,59 +65,62 @@ def mha_flash_attention(q, k, v, *, causal: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
                                              "interpret"))
-def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
-                           window: Optional[int] = None,
+def paged_decode_attention(q, k_pages, v_pages, layer, block_tables,
+                           lengths, *, window: Optional[int] = None,
                            softcap: Optional[float] = None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
     """Model-layout paged decode attention.
 
     q: (B, 1, H, D) — one decoding token per sequence, H = G * KV (GQA);
-    k_pages, v_pages: (num_pages, page_size, KV, D) block storage;
-    block_tables: (B, pages_per_seq) int32; lengths: (B,) valid positions
-    per sequence including the current token.  Returns (B, 1, H, D).
+    k_pages, v_pages: (layers, num_pages, page_size, KV * D) stacked
+    block storage; layer: int32 scalar, the layer read; block_tables:
+    (B, pages_per_seq) int32; lengths: (B,) valid positions per sequence
+    including the current token.  Returns (B, 1, H, D).
     """
     if interpret is None:
         interpret = _default_interpret()
     B, S, H, D = q.shape
     assert S == 1, "paged attention is single-token decode"
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[-1] // D
     G = H // KV
     qf = q[:, 0].reshape(B, KV, G, D)
-    out = _pa.paged_attention(qf, k_pages, v_pages, block_tables, lengths,
-                              window=window, softcap=softcap, scale=scale,
-                              interpret=interpret)
+    out = _pa.paged_attention(qf, k_pages, v_pages, layer, block_tables,
+                              lengths, window=window, softcap=softcap,
+                              scale=scale, interpret=interpret)
     return out.reshape(B, 1, H, D)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
                                              "interpret"))
-def paged_prefill_attention(q, k_pages, v_pages, block_tables, start_pos,
-                            q_lens, *, window: Optional[int] = None,
+def paged_prefill_attention(q, k_pages, v_pages, layer, block_tables,
+                            start_pos, q_lens, *,
+                            window: Optional[int] = None,
                             softcap: Optional[float] = None,
                             scale: Optional[float] = None,
                             interpret: Optional[bool] = None):
     """Model-layout paged chunked-prefill attention.
 
     q: (B, C, H, D) — a chunk of C query tokens per sequence, H = G * KV
-    (GQA); k_pages, v_pages: (num_pages, page_size, KV, D) block storage
-    with the chunk's own K/V already scattered in; block_tables:
-    (B, pages_per_seq) int32; start_pos: (B,) absolute position of each
-    row's first query token; q_lens: (B,) valid query tokens per row
-    (rows/tokens past q_lens are padding and return zeros).
-    Returns (B, C, H, D).
+    (GQA); k_pages, v_pages: (layers, num_pages, page_size, KV * D)
+    stacked block storage with the chunk's own K/V already scattered into
+    ``layer``; layer: int32 scalar; block_tables: (B, pages_per_seq)
+    int32; start_pos: (B,) absolute position of each row's first query
+    token; q_lens: (B,) valid query tokens per row (rows/tokens past
+    q_lens are padding and return zeros).  Returns (B, C, H, D).
     """
     if interpret is None:
         interpret = _default_interpret()
     B, C, H, D = q.shape
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[-1] // D
     G = H // KV
     # fold (C, G) -> CG rows grouped per KV head: row c*G+g
     qf = q.reshape(B, C, KV, G, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, KV, C * G, D)
-    out = _pp.paged_prefill(qf, k_pages, v_pages, block_tables, start_pos,
-                            q_lens, group=G, window=window, softcap=softcap,
-                            scale=scale, interpret=interpret)
+    out = _pp.paged_prefill(qf, k_pages, v_pages, layer, block_tables,
+                            start_pos, q_lens, group=G, window=window,
+                            softcap=softcap, scale=scale,
+                            interpret=interpret)
     return out.reshape(B, KV, C, G, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, C, H, D)
 

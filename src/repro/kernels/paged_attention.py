@@ -1,25 +1,30 @@
 """Pallas TPU paged-attention decode: gather K/V through block tables.
 
 Single-token decode attention where K/V live in *block* (page) storage —
-``(num_pages, page_size, KV, D)`` — instead of one dense contiguous
-sequence axis per slot.  Each live sequence owns a per-slot row of a
-``(B, pages_per_seq)`` block table naming the pages that back its token
-positions in order; the pool hands pages out on demand, so the resident
-KV footprint tracks the tokens actually generated, not the worst case.
+a layer-stacked pool ``(layers, num_pages, page_size, KV * D)`` —
+instead of one dense contiguous sequence axis per slot.  Each live
+sequence owns a per-slot row of a ``(B, pages_per_seq)`` block table
+naming the pages that back its token positions in order; the pool hands
+pages out on demand, so the resident KV footprint tracks the tokens
+actually generated, not the worst case.
 
-TPU adaptation: the block table and per-sequence lengths ride in as
-*scalar-prefetch* operands (``pltpu.PrefetchScalarGridSpec``), so the
-page index feeding each K/V tile's DMA — ``table[b, i]`` — is known
-before the kernel body runs.  The grid is ``(B, pages_per_seq)`` with
+TPU adaptation: the layer index, the block table and per-sequence
+lengths ride in as *scalar-prefetch* operands
+(``pltpu.PrefetchScalarGridSpec``), so the ``(layer, table[b, i])`` page
+feeding each K/V tile's DMA is known before the kernel body runs, and
+the kernel reads the whole stacked pool where it lies: no layer is
+sliced out of it first.  The grid is ``(B, pages_per_seq)`` with
 the page axis innermost and sequential, so the online-softmax state
 ``(m, l, acc)`` of every KV head accumulates in VMEM scratch across
 pages exactly like the flash-attention kernel accumulates across KV
-tiles.  One K/V block is a whole page, ``(page_size, KV, D)``: Mosaic
-requires a block's last two dims to be (8, 128)-divisible or the
-array's own, so a one-head ``(page_size, 1, D)`` block is refused, and
-the kernel walks the KV heads of the page itself.  Pages past a
-sequence's length are skipped (their table entries point at the pool's
-trash page and the position mask kills any stray values).
+tiles.  One K/V block is a whole page, ``(page_size, KV * D)``: the
+pool keeps each position's KV heads side by side in one lane row, so a
+block's last two dims are the array's own (as Mosaic requires), a
+head-dim of 64 is not padded to 128 lanes, and XLA writes a token's row
+in place.  The kernel walks the KV heads of the page, head ``h`` in
+lanes ``[h * D, (h + 1) * D)``.  Pages past a sequence's length are
+skipped (their table entries point at the pool's trash page and the
+position mask kills any stray values).
 
 Features match the dense decode path: GQA (all G query heads of a KV
 head's group in one tile), sliding window, and
@@ -41,10 +46,10 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1.0e30
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(layer_ref, tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float,
                   window: Optional[int], softcap: Optional[float],
-                  page_size: int, kv_heads: int):
+                  page_size: int, kv_heads: int, head_dim: int):
     b = pl.program_id(0)
     i = pl.program_id(1)
     ni = pl.num_programs(1)
@@ -67,11 +72,12 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(reachable)
     def _compute():
         # one page block carries every KV head; each head's G query
-        # heads attend its (page, D) slice
+        # heads attend its (page, D) lanes
         for h in range(kv_heads):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
             q = q_ref[0, h].astype(jnp.float32)             # (G, D)
-            k = k_ref[0, :, h, :].astype(jnp.float32)       # (page, D)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
+            k = k_ref[:, lanes].astype(jnp.float32)         # (page, D)
+            v = v_ref[:, lanes].astype(jnp.float32)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * scale
@@ -103,7 +109,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
+def paged_attention(q, k_pages, v_pages, layer, block_tables, lengths, *,
                     scale: Optional[float] = None,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
@@ -111,7 +117,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """Paged single-token decode attention.
 
     q: (B, KV, G, D) — one query token per sequence, grouped GQA layout;
-    k_pages, v_pages: (num_pages, page_size, KV, D) block storage;
+    k_pages, v_pages: (layers, num_pages, page_size, KV * D) stacked block
+      storage, position p's head h in lanes ``[h * D, (h + 1) * D)``;
+    layer: int32 scalar — the layer whose pages are read;
     block_tables: (B, pages_per_seq) int32 — page ids backing positions
       ``[j*page_size, (j+1)*page_size)`` of sequence b (entries past the
       sequence's extent may be any in-range id; they are masked);
@@ -120,29 +128,31 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     Returns (B, KV, G, D) in q.dtype.
     """
     B, KV, G, D = q.shape
-    NP, page_size, KVp, Dp = k_pages.shape
-    assert (KVp, Dp) == (KV, D), (k_pages.shape, q.shape)
+    _, NP, page_size, width = k_pages.shape
+    assert width == KV * D, (k_pages.shape, q.shape)
     pages_per_seq = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     # garbage entries must still name a real page for the DMA
     tables = jnp.clip(block_tables.astype(jnp.int32), 0, NP - 1)
     lengths = lengths.astype(jnp.int32)
+    # all KV heads of one page of one layer per block: the block's last
+    # two dims (page_size, KV * D) are the array's own, as Mosaic requires
+    page = pl.BlockSpec((pl.squeezed, pl.squeezed, page_size, width),
+                        lambda b, i, lyr, tbl, lens:
+                        (lyr[0], tbl[b, i], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, pages_per_seq),
         in_specs=[
-            pl.BlockSpec((1, KV, G, D), lambda b, i, tbl, lens:
+            pl.BlockSpec((1, KV, G, D), lambda b, i, lyr, tbl, lens:
                          (b, 0, 0, 0)),
-            # all KV heads of one page per block: the block's last two
-            # dims (KV, D) are the array's own, as Mosaic requires
-            pl.BlockSpec((1, page_size, KV, D), lambda b, i, tbl, lens:
-                         (tbl[b, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, KV, D), lambda b, i, tbl, lens:
-                         (tbl[b, i], 0, 0, 0)),
+            page,
+            page,
         ],
-        out_specs=pl.BlockSpec((1, KV, G, D), lambda b, i, tbl, lens:
+        out_specs=pl.BlockSpec((1, KV, G, D), lambda b, i, lyr, tbl, lens:
                                (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((KV, G, 1), jnp.float32),    # running max m
@@ -152,8 +162,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, window=window,
-                          softcap=softcap, page_size=page_size, kv_heads=KV),
+                          softcap=softcap, page_size=page_size, kv_heads=KV,
+                          head_dim=D),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
-    )(tables, lengths, q, k_pages, v_pages)
+    )(layer, tables, lengths, q, k_pages, v_pages)

@@ -2,23 +2,24 @@
 
 The prefill counterpart of ``paged_attention.py``: causal attention for a
 ``(batch, chunk)`` tile of query tokens whose K/V history — including the
-chunk itself — lives in *block* (page) storage ``(num_pages, page_size,
-KV, D)``.  Each row of the batch is one sequence mid-prefill: its queries
-sit at absolute positions ``[start[b], start[b] + q_len[b])`` and attend
-every earlier position of the same sequence through the row's block
-table.  This is what lets the serving engine write prefill KV straight
-into pool blocks and never allocate the transient dense ``max_seq_len``
-stripe the chunked-prefill path used to fill before scattering.
+chunk itself — lives in *block* (page) storage, the layer-stacked pool
+``(layers, num_pages, page_size, KV * D)``.  Each row of the batch is
+one sequence mid-prefill: its queries sit at absolute positions
+``[start[b], start[b] + q_len[b])`` and attend every earlier position
+of the same sequence through the row's block table.  This is what lets
+the serving engine write prefill KV straight into pool blocks and never
+allocate the transient dense ``max_seq_len`` stripe the chunked-prefill
+path used to fill before scattering.
 
-TPU adaptation, mirroring the decode kernel: the block table and the
-per-row ``(start, q_len)`` scalars ride in as *scalar-prefetch* operands
-(``pltpu.PrefetchScalarGridSpec``), so the page id feeding each K/V
-tile's DMA — ``table[b, i]`` — is known before the kernel body runs.
-The grid is ``(B, pages_per_seq)`` with the page axis innermost and
-sequential; the online-softmax state ``(m, l, acc)`` of every KV head
-accumulates in VMEM scratch across pages.  One K/V block is a whole
-page, ``(page_size, KV, D)`` (a one-head block's last two dims would
-not be legal for Mosaic), and the kernel walks the page's KV heads.
+TPU adaptation, mirroring the decode kernel: the layer index, the block
+table and the per-row ``(start, q_len)`` scalars ride in as
+*scalar-prefetch* operands (``pltpu.PrefetchScalarGridSpec``), so the
+``(layer, table[b, i])`` page feeding each K/V tile's DMA is known
+before the kernel body runs.  The grid is ``(B, pages_per_seq)`` with
+the page axis innermost and sequential; the online-softmax state
+``(m, l, acc)`` of every KV head accumulates in VMEM scratch across
+pages.  One K/V block is a whole page of one layer, ``(page_size,
+KV * D)``, and the kernel walks the page's KV heads by lane range.
 The query tile folds ``(chunk, G)`` into one ``CG = chunk * G`` axis
 (row ``c * G + g``), so GQA costs one page DMA per page, never per
 query head; the per-row chunk index is recovered in-kernel as
@@ -46,10 +47,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1.0e30
 
 
-def _paged_prefill_kernel(tbl_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
-                          o_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                          window: Optional[int], softcap: Optional[float],
-                          page_size: int, group: int, kv_heads: int):
+def _paged_prefill_kernel(layer_ref, tbl_ref, start_ref, qlen_ref, q_ref,
+                          k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                          scale: float, window: Optional[int],
+                          softcap: Optional[float], page_size: int,
+                          group: int, kv_heads: int, head_dim: int):
     b = pl.program_id(0)
     i = pl.program_id(1)
     ni = pl.num_programs(1)
@@ -73,11 +75,12 @@ def _paged_prefill_kernel(tbl_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
     @pl.when(reachable)
     def _compute():
         # one page block carries every KV head; each head's CG query
-        # rows attend its (page, D) slice
+        # rows attend its (page, D) lanes
         for h in range(kv_heads):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
             q = q_ref[0, h].astype(jnp.float32)             # (CG, D)
-            k = k_ref[0, :, h, :].astype(jnp.float32)       # (page, D)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
+            k = k_ref[:, lanes].astype(jnp.float32)         # (page, D)
+            v = v_ref[:, lanes].astype(jnp.float32)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * scale
@@ -115,8 +118,8 @@ def _paged_prefill_kernel(tbl_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
         o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
-def paged_prefill(q, k_pages, v_pages, block_tables, start_pos, q_lens, *,
-                  group: int, scale: Optional[float] = None,
+def paged_prefill(q, k_pages, v_pages, layer, block_tables, start_pos,
+                  q_lens, *, group: int, scale: Optional[float] = None,
                   window: Optional[int] = None,
                   softcap: Optional[float] = None,
                   interpret: bool = False):
@@ -124,9 +127,11 @@ def paged_prefill(q, k_pages, v_pages, block_tables, start_pos, q_lens, *,
 
     q: (B, KV, CG, D) — CG = chunk * group, row ``c * group + g`` is
       query token c of the chunk for head g of the KV group;
-    k_pages, v_pages: (num_pages, page_size, KV, D) block storage, with
-      the chunk's own K/V already written at positions
-      ``[start_pos[b], start_pos[b] + q_lens[b])``;
+    k_pages, v_pages: (layers, num_pages, page_size, KV * D) stacked
+      block storage (position p's head h in lanes ``[h * D, (h + 1) *
+      D)``), with the chunk's own K/V already written at positions
+      ``[start_pos[b], start_pos[b] + q_lens[b])`` of ``layer``;
+    layer: int32 scalar — the layer whose pages are read;
     block_tables: (B, pages_per_seq) int32 — page ids backing positions
       ``[j*page_size, (j+1)*page_size)`` of sequence b (entries past the
       sequence's extent may be any id; they are clamped and masked);
@@ -136,31 +141,33 @@ def paged_prefill(q, k_pages, v_pages, block_tables, start_pos, q_lens, *,
     Returns (B, KV, CG, D) in q.dtype; padding query rows are zeros.
     """
     B, KV, CG, D = q.shape
-    NP, page_size, KVp, Dp = k_pages.shape
-    assert (KVp, Dp) == (KV, D), (k_pages.shape, q.shape)
+    _, NP, page_size, width = k_pages.shape
+    assert width == KV * D, (k_pages.shape, q.shape)
     assert CG % group == 0, (CG, group)
     pages_per_seq = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     # garbage entries must still name a real page for the DMA
     tables = jnp.clip(block_tables.astype(jnp.int32), 0, NP - 1)
     start_pos = start_pos.astype(jnp.int32)
     q_lens = q_lens.astype(jnp.int32)
+    # all KV heads of one page of one layer per block: the block's last
+    # two dims (page_size, KV * D) are the array's own, as Mosaic requires
+    page = pl.BlockSpec((pl.squeezed, pl.squeezed, page_size, width),
+                        lambda b, i, lyr, tbl, st, ql:
+                        (lyr[0], tbl[b, i], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, pages_per_seq),
         in_specs=[
-            pl.BlockSpec((1, KV, CG, D), lambda b, i, tbl, st, ql:
+            pl.BlockSpec((1, KV, CG, D), lambda b, i, lyr, tbl, st, ql:
                          (b, 0, 0, 0)),
-            # all KV heads of one page per block: the block's last two
-            # dims (KV, D) are the array's own, as Mosaic requires
-            pl.BlockSpec((1, page_size, KV, D), lambda b, i, tbl, st, ql:
-                         (tbl[b, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, KV, D), lambda b, i, tbl, st, ql:
-                         (tbl[b, i], 0, 0, 0)),
+            page,
+            page,
         ],
-        out_specs=pl.BlockSpec((1, KV, CG, D), lambda b, i, tbl, st, ql:
+        out_specs=pl.BlockSpec((1, KV, CG, D), lambda b, i, lyr, tbl, st, ql:
                                (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((KV, CG, 1), jnp.float32),   # running max m
@@ -171,8 +178,8 @@ def paged_prefill(q, k_pages, v_pages, block_tables, start_pos, q_lens, *,
     return pl.pallas_call(
         functools.partial(_paged_prefill_kernel, scale=scale, window=window,
                           softcap=softcap, page_size=page_size, group=group,
-                          kv_heads=KV),
+                          kv_heads=KV, head_dim=D),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, CG, D), q.dtype),
         interpret=interpret,
-    )(tables, start_pos, q_lens, q, k_pages, v_pages)
+    )(layer, tables, start_pos, q_lens, q, k_pages, v_pages)

@@ -35,27 +35,35 @@ def attention_ref(q, k, v, *, causal: bool = True,
     return jnp.where(any_valid, out, 0.0).astype(q.dtype)
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
-                        scale: Optional[float] = None,
+def _gather_pages(pages, layer, tables, B, T, KV, D):
+    """One layer's pages of a stacked (layers, num_pages, page_size,
+    KV * D) pool, gathered through the block tables into (B, T, KV, D)."""
+    NP = pages.shape[1]
+    tables = jnp.clip(tables.astype(jnp.int32), 0, NP - 1)
+    return pages[layer][tables.reshape(-1)].reshape(B, T, KV, D)
+
+
+def paged_attention_ref(q, k_pages, v_pages, layer, block_tables, lengths,
+                        *, scale: Optional[float] = None,
                         window: Optional[int] = None,
                         softcap: Optional[float] = None):
     """Oracle for the paged decode kernel: gather every sequence's pages
     back into a dense (B, T, KV, D) layout, then run naive masked softmax
     attention for the single query token.
 
-    q: (B, KV, G, D); k_pages, v_pages: (num_pages, page_size, KV, D);
-    block_tables: (B, pages_per_seq) int32; lengths: (B,) int32 counting
-    valid positions including the current token.  Returns (B, KV, G, D).
+    q: (B, KV, G, D); k_pages, v_pages: (layers, num_pages, page_size,
+    KV * D); layer: the layer read; block_tables: (B, pages_per_seq)
+    int32; lengths: (B,) int32 counting valid positions including the
+    current token.  Returns (B, KV, G, D).
     """
     B, KV, G, D = q.shape
-    NP, page_size = k_pages.shape[0], k_pages.shape[1]
+    page_size = k_pages.shape[2]
     pages_per_seq = block_tables.shape[1]
     T = pages_per_seq * page_size
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0, NP - 1)
-    k = k_pages[tables.reshape(-1)].reshape(B, T, KV, D)
-    v = v_pages[tables.reshape(-1)].reshape(B, T, KV, D)
+    k = _gather_pages(k_pages, layer, block_tables, B, T, KV, D)
+    v = _gather_pages(v_pages, layer, block_tables, B, T, KV, D)
     s = jnp.einsum("bkgd,btkd->bkgt", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if softcap is not None:
@@ -72,8 +80,8 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     return jnp.where(any_valid, out, 0.0).astype(q.dtype)
 
 
-def paged_prefill_ref(q, k_pages, v_pages, block_tables, start_pos, q_lens,
-                      *, scale: Optional[float] = None,
+def paged_prefill_ref(q, k_pages, v_pages, layer, block_tables, start_pos,
+                      q_lens, *, scale: Optional[float] = None,
                       window: Optional[int] = None,
                       softcap: Optional[float] = None):
     """Oracle for the paged chunked-prefill kernel: gather every row's
@@ -81,21 +89,21 @@ def paged_prefill_ref(q, k_pages, v_pages, block_tables, start_pos, q_lens,
     softmax attention for the whole query chunk.
 
     q: (B, C, KV, G, D) — chunk of query tokens per row, GQA-grouped;
-    k_pages, v_pages: (num_pages, page_size, KV, D) block storage holding
-    the chunk's own K/V at its absolute positions; block_tables:
-    (B, pages_per_seq) int32; start_pos: (B,) absolute position of each
-    row's first query; q_lens: (B,) valid query tokens per row (padding
-    rows/tokens return zeros).  Returns (B, C, KV, G, D).
+    k_pages, v_pages: (layers, num_pages, page_size, KV * D) stacked
+    block storage holding the chunk's own K/V at its absolute positions
+    of ``layer``; block_tables: (B, pages_per_seq) int32; start_pos:
+    (B,) absolute position of each row's first query; q_lens: (B,) valid
+    query tokens per row (padding rows/tokens return zeros).  Returns
+    (B, C, KV, G, D).
     """
     B, C, KV, G, D = q.shape
-    NP, page_size = k_pages.shape[0], k_pages.shape[1]
+    page_size = k_pages.shape[2]
     pages_per_seq = block_tables.shape[1]
     T = pages_per_seq * page_size
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0, NP - 1)
-    k = k_pages[tables.reshape(-1)].reshape(B, T, KV, D)
-    v = v_pages[tables.reshape(-1)].reshape(B, T, KV, D)
+    k = _gather_pages(k_pages, layer, block_tables, B, T, KV, D)
+    v = _gather_pages(v_pages, layer, block_tables, B, T, KV, D)
     s = jnp.einsum("bckgd,btkd->bkgct", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if softcap is not None:

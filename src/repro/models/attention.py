@@ -139,16 +139,18 @@ def attend(q, k, v, *, scale: float, causal: bool,
 def attention_block(params, cfg, x, *, positions=None, causal: bool = True,
                     window: Optional[int] = None, cache=None,
                     cache_index=None, kv_override=None, use_rope: bool = True,
-                    block_tables=None, q_lens=None):
+                    block_tables=None, q_lens=None, layer=None):
     """x: (B, S, d_model).  Returns (out, new_cache).
 
     positions: (B, S) or (3, B, S) for M-RoPE (defaults to broadcast arange).
     cache: {"k": (B, Smax, KV, D), "v": ...} — decode mode, S must be 1 and
       cache_index (B,) gives each sequence's write position.
-    block_tables: (B, blocks_per_slot) int32 — paged mode: cache leaves
-      are block storage {"k": (num_blocks, block_size, KV, D), ...}; this
-      step's k/v are scattered to (table[b, pos//bs], pos%bs) and
-      attention gathers through the table with the Pallas paged kernels.
+    block_tables: (B, blocks_per_slot) int32 — paged mode: cache is the
+      whole stacked pool {"k": (layers, num_blocks, block_size, KV * D),
+      ...} and ``layer`` this layer's index into it; this step's k/v
+      rows are scattered in place to (layer, table[b, pos//bs], pos%bs)
+      and attention gathers through the table with the Pallas paged
+      kernels, which read the pool's (layer, page) blocks where they lie.
       S == 1 is single-token decode; S > 1 is a *chunked-prefill* tile:
       row b's queries sit at absolute positions ``cache_index[b] + t``,
       their K/V land straight in the row's pool blocks (padding tokens —
@@ -187,29 +189,28 @@ def attention_block(params, cfg, x, *, positions=None, causal: bool = True,
         assert "k_scale" not in cache, "paged int8 KV unsupported"
         from repro.kernels import ops as kops
         idx = cache_index                                        # (B,) int32
-        bs = cache["k"].shape[1]                                 # block size
+        _, npages, bs, _ = cache["k"].shape
         if S == 1:
-            # paged decode: scatter this step's k/v into block storage
+            # paged decode: scatter this step's k/v rows into the pool
             # through the table, then gather-attend with the Pallas kernel
             rows = jnp.arange(B)
             blk = block_tables[rows, idx // bs]
             off = idx % bs
-            upd_k = cache["k"].at[blk, off].set(
-                k[:, 0].astype(cache["k"].dtype))
-            upd_v = cache["v"].at[blk, off].set(
-                v[:, 0].astype(cache["v"].dtype))
+            upd_k = cache["k"].at[layer, blk, off].set(
+                k[:, 0].reshape(B, kv * dh).astype(cache["k"].dtype))
+            upd_v = cache["v"].at[layer, blk, off].set(
+                v[:, 0].reshape(B, kv * dh).astype(cache["v"].dtype))
             new_cache = {"k": upd_k, "v": upd_v}
             # kernel casts tiles to f32 in VMEM; bf16 pages go in as-is
             out = kops.paged_decode_attention(
-                q.reshape(B, S, h, dh), upd_k, upd_v, block_tables, idx + 1,
-                window=window, softcap=sc, scale=scale)
+                q.reshape(B, S, h, dh), upd_k, upd_v, layer, block_tables,
+                idx + 1, window=window, softcap=sc, scale=scale)
             out = out.reshape(B, S, kv, g, dh)
         else:
             # paged chunked prefill: the whole (B, S) tile's k/v go
             # straight into each row's pool blocks; padding tokens (past
             # q_lens, or past the table's extent) land in the trailing
             # trash block, never a live page
-            npages = cache["k"].shape[0]
             bps = block_tables.shape[1]
             qlv = (jnp.full((B,), S, jnp.int32) if q_lens is None
                    else q_lens.astype(jnp.int32))
@@ -222,12 +223,14 @@ def attention_block(params, cfg, x, *, positions=None, causal: bool = True,
                 block_tables[rows, jnp.clip(pos // bs, 0, bps - 1)],
                 npages - 1)
             off = pos % bs
-            upd_k = cache["k"].at[blk, off].set(k.astype(cache["k"].dtype))
-            upd_v = cache["v"].at[blk, off].set(v.astype(cache["v"].dtype))
+            upd_k = cache["k"].at[layer, blk, off].set(
+                k.reshape(B, S, kv * dh).astype(cache["k"].dtype))
+            upd_v = cache["v"].at[layer, blk, off].set(
+                v.reshape(B, S, kv * dh).astype(cache["v"].dtype))
             new_cache = {"k": upd_k, "v": upd_v}
             out = kops.paged_prefill_attention(
-                q.reshape(B, S, h, dh), upd_k, upd_v, block_tables, idx,
-                qlv, window=window, softcap=sc, scale=scale)
+                q.reshape(B, S, h, dh), upd_k, upd_v, layer, block_tables,
+                idx, qlv, window=window, softcap=sc, scale=scale)
             out = out.reshape(B, S, kv, g, dh)
     elif cache is not None and kv_override is None:
         # decode: write this step's k/v at cache_index, attend over the cache

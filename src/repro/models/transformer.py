@@ -81,14 +81,15 @@ def _stack_init(fn, keys):
 
 def _apply_attn_mlp_layer(p, cfg, x, *, window, positions=None, causal=True,
                           cache=None, cache_index=None, encoder_out=None,
-                          use_rope=True, block_tables=None, q_lens=None):
+                          use_rope=True, block_tables=None, q_lens=None,
+                          layer=None):
     """Pre-norm attention + (cross-attention) + MLP/MoE.  Returns
     (x, new_cache, aux)."""
     h = _norm(cfg, p["ln1"], x)
     a, new_cache = attn.attention_block(
         p["attn"], cfg, h, positions=positions, causal=causal, window=window,
         cache=cache, cache_index=cache_index, use_rope=use_rope,
-        block_tables=block_tables, q_lens=q_lens)
+        block_tables=block_tables, q_lens=q_lens, layer=layer)
     if cfg.post_block_norm:
         a = _norm(cfg, p["post_ln1"], a)
     x = x + a
@@ -239,6 +240,23 @@ def init_cache(cfg, batch: int, seq_len: int):
                         _cache_struct(cfg, batch, seq_len),
                         is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
                         and isinstance(x[0], tuple))
+
+
+def init_paged_cache(cfg, num_blocks: int, block_size: int):
+    """The paged KV pool: ``{"k", "v"}``, each ``(num_layers, num_blocks,
+    block_size, KV * D)`` bf16 — every attention layer's blocks in one
+    stacked array, position p's KV head h in lanes ``[h * D, (h + 1) *
+    D)`` of the block's row ``p % block_size``.
+
+    This is the layout the paged kernels' page blocks read, and the one a
+    token's K/V row is scattered into in place, so the paged programs
+    carry the pool through their layer loop with no per-layer slice,
+    relayout or restack (a ``(…, KV, D)`` minor pair would be padded and
+    relaid out on the TPU)."""
+    shape = (cfg.num_layers, num_blocks, block_size,
+             cfg.num_kv_heads * cfg.resolved_head_dim)
+    return {"k": jnp.zeros(shape, jnp.bfloat16),
+            "v": jnp.zeros(shape, jnp.bfloat16)}
 
 
 # ---------------------------------------------------------------------------
@@ -400,12 +418,11 @@ def _encode(enc_params, cfg, encoder_input):
 
 def decode_step(params, cfg, batch: Dict[str, Any], *,
                 long_context: bool = False) -> Tuple[jnp.ndarray, Any]:
-    """One-token decode.  batch: tokens (B,1), positions (B,), cache, plus
-    encoder_output / mrope_positions when applicable.  With
-    ``block_tables`` (B, blocks_per_slot) in the batch, the attention
-    cache leaves are block storage and K/V are gathered through the
-    tables (paged attention) instead of the dense per-slot layout.
-    Returns (logits (B, 1, V) f32, new_cache)."""
+    """One-token decode against the dense per-slot cache.  batch: tokens
+    (B,1), positions (B,), cache, plus encoder_output / mrope_positions
+    when applicable.  (The paged pool decodes through
+    :func:`prefill_step` with one-token chunks.)  Returns (logits
+    (B, 1, V) f32, new_cache)."""
     dt = jnp.dtype(cfg.dtype)
     tokens, idx, cache = batch["tokens"], batch["positions"], batch["cache"]
     B = tokens.shape[0]
@@ -420,7 +437,6 @@ def decode_step(params, cfg, batch: Dict[str, Any], *,
     if positions is None:
         positions = idx[:, None]                                   # (B,1)
     encoder_out = batch.get("encoder_output")
-    block_tables = batch.get("block_tables")
     windows = layer_pattern(cfg, long_context)
     use_rope = cfg.max_pos_embed == 0
     new_cache = dict(cache)
@@ -440,8 +456,7 @@ def decode_step(params, cfg, batch: Dict[str, Any], *,
                     lc = jax.tree.map(lambda a: a[i], gc)
                     x, nc_i, _ = _apply_attn_mlp_layer(
                         lp, cfg, x, window=win, positions=positions, cache=lc,
-                        cache_index=idx, use_rope=use_rope,
-                        block_tables=block_tables)
+                        cache_index=idx, use_rope=use_rope)
                     ncs.append(nc_i)
                 nc = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
             return x, nc
@@ -498,7 +513,7 @@ def decode_step(params, cfg, batch: Dict[str, Any], *,
 
 
 # ---------------------------------------------------------------------------
-# Paged chunked-prefill step (a (B, C) query tile against block storage)
+# Paged steps: the stacked pool carried through the layer loop
 # ---------------------------------------------------------------------------
 
 def prefill_step(params, cfg, batch: Dict[str, Any], *,
@@ -508,24 +523,30 @@ def prefill_step(params, cfg, batch: Dict[str, Any], *,
     batch: tokens (B, C); positions (B,) — each row's *start* position
     (row b's token t sits at absolute position ``positions[b] + t``);
     q_lens (B,) — valid tokens per row (padding tokens and whole padding
-    rows are masked and their K/V routed to the trash block); cache —
-    paged block storage; block_tables (B, blocks_per_slot).
+    rows are masked and their K/V routed to the trash block; absent:
+    all C); cache — the paged pool (:func:`init_paged_cache`);
+    block_tables (B, blocks_per_slot).
 
     The chunk's K/V are written straight into each row's pool blocks and
     attention gathers the full history (earlier chunks + this one)
     through the tables with the Pallas paged-prefill kernel, so paged
-    prefill never materializes a dense ``max_seq_len`` stripe.  Returns
-    (logits (B, C, V) f32, new_cache); logits at padding positions are
-    garbage — callers index only real tokens.
+    prefill never materializes a dense ``max_seq_len`` stripe.  With
+    C == 1 this is one paged decode step: the attention layer writes
+    each row's K/V at its position and runs the paged decode kernel.
+    The whole stacked pool rides in the layer loop's carry: layer ``l``
+    (scan group ``g``, member ``i``: ``l = g * len(pattern) + i``)
+    scatters its rows into ``pool[:, l]`` in place and its kernel reads
+    ``(l, page)`` blocks of the same buffer, so no layer's pages are
+    sliced out, relaid out or restacked.  Returns (logits (B, C, V) f32,
+    new pool); logits at padding positions are garbage — callers index
+    only real tokens.
     """
-    dt = jnp.dtype(cfg.dtype)
-    tokens, starts, cache = batch["tokens"], batch["positions"], batch["cache"]
-    q_lens = batch["q_lens"]
-    tables = batch["block_tables"]
     if cfg.family not in ("dense", "vlm", "moe"):
         raise ValueError(
             f"paged prefill needs a positional attention cache; family "
             f"{cfg.family!r} unsupported")
+    dt = jnp.dtype(cfg.dtype)
+    tokens, starts = batch["tokens"], batch["positions"]
     B, C = tokens.shape
     positions = (starts.astype(jnp.int32)[:, None]
                  + jnp.arange(C, dtype=jnp.int32)[None])           # (B, C)
@@ -539,30 +560,29 @@ def prefill_step(params, cfg, batch: Dict[str, Any], *,
 
     windows = layer_pattern(cfg, long_context)
     use_rope = cfg.max_pos_embed == 0
-    new_cache = dict(cache)
 
-    def body(x, xs):
-        gp, gc = xs
-        ncs = []
+    def body(carry, xs):
+        x, pool = carry
+        gp, g = xs
         for i, win in enumerate(windows):
             lp = jax.tree.map(lambda a: a[i], gp)
-            lc = jax.tree.map(lambda a: a[i], gc)
-            x, nc_i, _ = _apply_attn_mlp_layer(
-                lp, cfg, x, window=win, positions=positions, cache=lc,
+            x, pool, _ = _apply_attn_mlp_layer(
+                lp, cfg, x, window=win, positions=positions, cache=pool,
                 cache_index=starts, use_rope=use_rope,
-                block_tables=tables, q_lens=q_lens)
-            ncs.append(nc_i)
-        return x, jax.tree.map(lambda *a: jnp.stack(a), *ncs)
+                block_tables=batch["block_tables"],
+                q_lens=batch.get("q_lens"), layer=g * len(windows) + i)
+        return (x, pool), None
 
-    x, nc = _scan_layers(body, x, params["layers"], (cache["layers"],),
-                         False, cfg.scan_layers)
-    new_cache["layers"] = nc
+    groups = jax.tree.leaves(params["layers"])[0].shape[0]
+    (x, pool), _ = _scan_layers(
+        body, (x, batch["cache"]), params["layers"],
+        (jnp.arange(groups, dtype=jnp.int32),), False, cfg.scan_layers)
 
     x = _norm(cfg, params["final_norm"], x)
     logits = (nn.unembed(params["embed"], x) if cfg.tie_embeddings
               else nn.linear(params["lm_head"], x, dtype=dt).astype(jnp.float32))
     logits = nn.softcap(logits, cfg.final_logit_softcap)
-    return logits, new_cache
+    return logits, pool
 
 
 # ---------------------------------------------------------------------------

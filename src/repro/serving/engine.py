@@ -35,9 +35,11 @@ pooled decode cache (a :class:`~repro.serving.kvcache.PagedKVCache` over
   while cursors are in flight their slots' block-table rows are masked
   to the trash block, so the dummy decode rows of mid-prefill slots can
   never corrupt the KV the prefill already wrote;
-  ``serve_step`` here is the exact program the decode dry-run shapes
-  lower.  Logits stay **on device**; the host transfer is deferred to
-  ``sample_tokens`` so each decode step costs one sync, not two.
+  the dense ``serve_step`` is the exact program the decode dry-run
+  shapes lower; the paged one takes the pool as its own, donated
+  argument and updates it in place.  Logits stay **on device**; the
+  host transfer is deferred to ``sample_tokens`` so each decode step
+  costs one sync, not two.
 
 Sampling is vectorized per slot (``sample_tokens``): each row gets its own
 temperature / greedy flag, fixing the seed bug where ``requests[0].params``
@@ -133,6 +135,17 @@ def make_serve_step(cfg, *, long_context: bool = False):
     return serve_step
 
 
+def make_paged_serve_step(cfg):
+    """serve_step(params, batch, pool) -> (logits, new_pool): the paged
+    decode program, a one-token ``T.prefill_step`` chunk per row.  batch
+    carries tokens (B,1), positions (B,) and block_tables; the pool
+    rides alone so that it, and only it, can be donated — the engine's
+    cached device block tables stay alive."""
+    def serve_step(params, batch, pool):
+        return T.prefill_step(params, cfg, {**batch, "cache": pool})
+    return serve_step
+
+
 class ServingEngine:
     """Fixed-slot batched engine (continuous batching over ``max_slots``).
 
@@ -221,9 +234,13 @@ class ServingEngine:
         self.recompiles = RecompilationTracker()
         self._inflight: Dict[int, PrefillCursor] = {}   # slot -> cursor
         self._begin_seq = 0                  # FIFO stamp for cursors
-        self._step = jax.jit(make_serve_step(cfg))
-
         if paged:
+            # both paged programs take the pool donated and update it in
+            # place: the buffer passed in is deleted, so callers keep
+            # only the returned pool
+            self._step = jax.jit(make_paged_serve_step(cfg),
+                                 donate_argnums=2)
+
             def prefill_paged(params, tokens, starts, q_lens, cache, tables):
                 """One co-prefill round: (Bp, C) chunk straight into the
                 rows' pool blocks.  ONE compiled program for every wave
@@ -234,6 +251,8 @@ class ServingEngine:
                 return T.prefill_step(params, cfg, batch)
 
             self._prefill_paged = jax.jit(prefill_paged, donate_argnums=4)
+        else:
+            self._step = jax.jit(make_serve_step(cfg))
 
         def prefill(params, tokens, cache, encoder_output):
             """Replay (B, P) prompt tokens through decode_step via scan."""
@@ -591,8 +610,10 @@ class ServingEngine:
                     int(positions[s]) + 1 for s in self.kv.block_table
                     if s not in self._inflight))
             batch = {"tokens": jnp.asarray(tokens, jnp.int32)[:, None],
-                     "positions": jnp.asarray(positions, jnp.int32),
-                     "cache": self.kv.cache}
+                     "positions": jnp.asarray(positions, jnp.int32)}
+            self.recompiles.observe(
+                "decode_step", (np.shape(tokens), np.shape(positions)),
+                tracer=self.tracer)
             if self.paged:
                 # free slots' rows point at the trash block; their dummy
                 # writes and speculative gathers never touch live KV.  A
@@ -602,12 +623,13 @@ class ServingEngine:
                 # the prefill just produced
                 batch["block_tables"] = self.kv.device_block_tables(
                     mask_slots=self._inflight)
-            if self._enc_pool is not None:
-                batch["encoder_output"] = self._enc_pool
-            self.recompiles.observe(
-                "decode_step", (np.shape(tokens), np.shape(positions)),
-                tracer=self.tracer)
-            logits, self.kv.cache = self._step(self.params, batch)
+                logits, self.kv.cache = self._step(self.params, batch,
+                                                   self.kv.cache)
+            else:
+                batch["cache"] = self.kv.cache
+                if self._enc_pool is not None:
+                    batch["encoder_output"] = self._enc_pool
+                logits, self.kv.cache = self._step(self.params, batch)
         self.decode_steps += 1
         return logits[:, 0]                  # device-resident; no sync here
 

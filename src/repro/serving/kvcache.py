@@ -7,9 +7,11 @@ Two physical layouts share one ledger:
   ``max_seq_len`` stripe, so the pool can never actually run dry; the
   block tables are accounting only.
 * **paged** (``paged=True``) — attention KV lives in *block* storage:
-  every cache leaf's batch axis indexes ``num_blocks + 1`` physical KV
-  blocks and its sequence axis is one block (``block_size`` positions)
-  wide.  A device-resident ``(max_slots, blocks_per_slot)`` block-table
+  ``T.init_paged_cache``'s stacked pool, ``{"k", "v"}`` each
+  ``(num_layers, num_blocks + 1, block_size, KV * D)``, the layout the
+  paged kernels read and the paged programs update in place (they take
+  the pool donated: a caller never keeps the ``cache`` it passed in).
+  A device-resident ``(max_slots, blocks_per_slot)`` block-table
   tensor maps each slot's token positions to blocks, the decode step
   gathers K/V through it with the Pallas paged-attention kernel, and the
   pool may be sized **smaller than worst case** via the ``num_blocks``
@@ -151,10 +153,11 @@ class PagedKVCache:
     scatters a batch-1 cache (a fresh prefill) into one slot; the
     per-leaf batch-axis index is detected from the model's cache spec,
     so every family (dense, MoE, VLM, SSM, hybrid, enc-dec) works
-    unmodified.  Paged mode (``paged=True``): every leaf's batch axis
-    indexes ``num_blocks + 1`` KV blocks and its sequence axis is one
-    block wide; prefill chunks and decode steps write straight into the
-    blocks through the tables (no staging cache),
+    unmodified.  Paged mode (``paged=True``): ``cache`` is the stacked
+    pool of ``T.init_paged_cache`` with ``num_blocks + 1`` blocks (axis
+    1 of each leaf); prefill chunks and decode steps write straight into
+    the blocks through the tables (no staging cache) and donate the pool
+    they were given,
     ``device_block_tables()`` feeds the Pallas paged-attention gathers,
     and the ``num_blocks`` knob may undersize the pool below
     ``max_slots * blocks_per_slot`` (real ``OutOfBlocks``).
@@ -207,7 +210,7 @@ class PagedKVCache:
                     f"a multiple of block_size ({block_size})")
             # +1 physical block: the trash block free/dummy rows write to
             self.trash_block = num_blocks
-            self.cache = self._init_store(num_blocks + 1)
+            self.cache = T.init_paged_cache(cfg, num_blocks + 1, block_size)
             self._tables = np.full((max_slots, self.blocks_per_slot),
                                    self.trash_block, np.int32)
             self._tables_dev = None
@@ -227,14 +230,21 @@ class PagedKVCache:
                     f"family {cfg.family!r} has a non-positional decode "
                     "cache; prefix caching unsupported")
             self.prefix_pool = KVBlockPool(prefix_blocks, block_size)
-            self.prefix_store = self._init_store(prefix_blocks)
             if paged:
-                self._save_paged = jax.jit(self._make_save_paged(),
-                                           donate_argnums=0)
+                # the store shares the pool's layout: saves, loads and
+                # copy-on-write forks are block-to-block copies on axis 1
+                self.prefix_store = T.init_paged_cache(cfg, prefix_blocks,
+                                                       block_size)
+                save = self._make_save_paged()
+                self._save_paged = jax.jit(save, donate_argnums=0)
+                self._copy = jax.jit(
+                    lambda store, src, dst: save(store, store, src, dst),
+                    donate_argnums=0)
             else:
+                self.prefix_store = self._init_store(prefix_blocks)
                 self._save = jax.jit(self._make_save(), donate_argnums=0)
-            self._load = jax.jit(self._make_load(), donate_argnums=0)
-            self._copy = jax.jit(self._make_copy(), donate_argnums=0)
+                self._load = jax.jit(self._make_load(), donate_argnums=0)
+                self._copy = jax.jit(self._make_copy(), donate_argnums=0)
 
     # -- batch-axis detection ------------------------------------------------
 
@@ -372,20 +382,13 @@ class PagedKVCache:
     def _make_save_paged(self):
         """prefix_store[dst] <- block_storage[src] — in paged mode a
         prefix snapshot is a straight block-to-block copy (both trees
-        share the (blocks, block_size) leaf layout)."""
-        baxes = self._axes
-
+        share the stacked pool's layout, blocks on axis 1)."""
         def save(store, storage, src, dst):
-            leaves_st, treedef = jax.tree.flatten(store)
-            leaves_bs = jax.tree.leaves(storage)
-            out = []
-            for lst, lbs, bax in zip(leaves_st, leaves_bs, baxes):
-                piece = jax.lax.dynamic_index_in_dim(lbs, src, axis=bax,
-                                                     keepdims=True)
-                starts = [jnp.int32(0)] * lst.ndim
-                starts[bax] = dst
-                out.append(jax.lax.dynamic_update_slice(lst, piece, starts))
-            return jax.tree.unflatten(treedef, out)
+            return jax.tree.map(
+                lambda lst, lbs: jax.lax.dynamic_update_index_in_dim(
+                    lst, jax.lax.dynamic_index_in_dim(lbs, src, axis=1),
+                    dst, axis=1),
+                store, storage)
 
         return save
 

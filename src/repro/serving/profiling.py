@@ -97,8 +97,12 @@ def profile_paged_kernels(engine, reps: int = 3,
     pages, page = kv.pool.num_blocks + 1, kv.block_size   # + trash block
     rng = np.random.default_rng(0)
     q1 = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((pages, page, KV, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((pages, page, KV, D)), jnp.float32)
+    # one layer of the engine's stacked pool layout
+    kp = jnp.asarray(rng.standard_normal((1, pages, page, KV * D)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((1, pages, page, KV * D)),
+                     jnp.float32)
+    layer = jnp.int32(0)
     tables = jnp.asarray(
         np.arange(B * kv.blocks_per_slot, dtype=np.int32).reshape(
             B, kv.blocks_per_slot) % kv.pool.num_blocks)
@@ -110,11 +114,11 @@ def profile_paged_kernels(engine, reps: int = 3,
     qlens = jnp.full((B,), C, jnp.int32)
     return {
         "paged_attention": profile_kernel(
-            ops.paged_decode_attention, q1, kp, vp, tables, lengths,
+            ops.paged_decode_attention, q1, kp, vp, layer, tables, lengths,
             name="paged_attention", reps=reps),
         "paged_prefill": profile_kernel(
-            ops.paged_prefill_attention, qc, kp, vp, tables, starts, qlens,
-            name="paged_prefill", reps=reps),
+            ops.paged_prefill_attention, qc, kp, vp, layer, tables, starts,
+            qlens, name="paged_prefill", reps=reps),
     }
 
 

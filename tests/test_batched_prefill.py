@@ -21,11 +21,15 @@ def qwen():
     return cfg, params
 
 
+LAYERS, LAYER = 3, 1      # a stacked pool; the kernels read its middle layer
+
+
 def _pages(key, B, C, KV, G, D, NP, page, pps, dtype=jnp.float32):
+    """q and a stacked (LAYERS, NP, page, KV * D) K/V pool of noise."""
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (B, C, KV, G, D), dtype)
-    kp = jax.random.normal(ks[1], (NP, page, KV, D), dtype)
-    vp = jax.random.normal(ks[2], (NP, page, KV, D), dtype)
+    kp = jax.random.normal(ks[1], (LAYERS, NP, page, KV * D), dtype)
+    vp = jax.random.normal(ks[2], (LAYERS, NP, page, KV * D), dtype)
     tbl = jax.random.randint(ks[3], (B, pps), 0, NP, jnp.int32)
     return q, kp, vp, tbl
 
@@ -48,13 +52,19 @@ def test_prefill_kernel_matches_ref(B, C, KV, G, D, NP, page, pps, window,
     start = jnp.array([(5 * b + 3) % (T_ - C) for b in range(B)], jnp.int32)
     qlens = jnp.array([max(0, C - b) for b in range(B)], jnp.int32)
     qf = q.transpose(0, 2, 1, 3, 4).reshape(B, KV, C * G, D)
-    out = paged_prefill(qf, kp, vp, tbl, start, qlens, group=G,
+    out = paged_prefill(qf, kp, vp, LAYER, tbl, start, qlens, group=G,
                         window=window, softcap=softcap, interpret=True)
-    out = out.reshape(B, KV, C, G, D).transpose(0, 2, 1, 3, 4)
-    ref = paged_prefill_ref(q, kp, vp, tbl, start, qlens, window=window,
-                            softcap=softcap)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-6, rtol=2e-6)
+    ref = paged_prefill_ref(q, kp, vp, LAYER, tbl, start, qlens,
+                            window=window, softcap=softcap)
+    np.testing.assert_allclose(
+        np.asarray(out.reshape(B, KV, C, G, D).transpose(0, 2, 1, 3, 4)),
+        np.asarray(ref), atol=2e-6, rtol=2e-6)
+    # the other layers' pages are never read
+    other = jnp.zeros_like(vp).at[LAYER].set(vp[LAYER])
+    np.testing.assert_array_equal(
+        np.asarray(paged_prefill(qf, kp, other, LAYER, tbl, start, qlens,
+                                 group=G, window=window, softcap=softcap,
+                                 interpret=True)), np.asarray(out))
 
 
 def test_prefill_ref_matches_dense_attention(rng_key):
@@ -63,21 +73,26 @@ def test_prefill_ref_matches_dense_attention(rng_key):
     layout, not math."""
     B, C, KV, G, D, page, pps = 2, 4, 2, 2, 16, 4, 4
     T_ = page * pps
-    ks = jax.random.split(rng_key, 3)
+    ks = jax.random.split(rng_key, 4)
     q = jax.random.normal(ks[0], (B, C, KV, G, D))
     k = jax.random.normal(ks[1], (B, T_, KV, D))
     v = jax.random.normal(ks[2], (B, T_, KV, D))
+    # one layer's pages (head h of position p in lanes [h*D, (h+1)*D)
+    # of its row); the other layers hold noise
     perm = np.random.default_rng(0).permutation(B * pps)
     tbl = jnp.asarray(perm.reshape(B, pps), jnp.int32)
-    kp = jnp.zeros((B * pps, page, KV, D))
-    vp = jnp.zeros((B * pps, page, KV, D))
+    noise = jax.random.normal(ks[3], (LAYERS, B * pps, page, KV * D))
+    kp, vp = noise, -noise
     for b in range(B):
         for j in range(pps):
-            kp = kp.at[perm[b * pps + j]].set(k[b, j * page:(j + 1) * page])
-            vp = vp.at[perm[b * pps + j]].set(v[b, j * page:(j + 1) * page])
+            rows = slice(j * page, (j + 1) * page)
+            kp = kp.at[LAYER, perm[b * pps + j]].set(
+                k[b, rows].reshape(page, KV * D))
+            vp = vp.at[LAYER, perm[b * pps + j]].set(
+                v[b, rows].reshape(page, KV * D))
     start = jnp.array([6, 9], jnp.int32)
     qlens = jnp.array([C, C], jnp.int32)
-    out = paged_prefill_ref(q, kp, vp, tbl, start, qlens)
+    out = paged_prefill_ref(q, kp, vp, LAYER, tbl, start, qlens)
     for b in range(B):
         s0 = int(start[b])
         L = s0 + C                           # newest attended position + 1
@@ -107,9 +122,9 @@ def test_ops_wrapper_gqa_layout(rng_key):
     q, kp, vp, tbl = _pages(rng_key, B, C, KV, G, D, NP, page, pps)
     start = jnp.array([2, 7], jnp.int32)
     qlens = jnp.array([3, 2], jnp.int32)
-    ref = paged_prefill_ref(q, kp, vp, tbl, start, qlens)
+    ref = paged_prefill_ref(q, kp, vp, LAYER, tbl, start, qlens)
     qm = q.reshape(B, C, KV * G, D)
-    out = ops.paged_prefill_attention(qm, kp, vp, tbl, start, qlens,
+    out = ops.paged_prefill_attention(qm, kp, vp, LAYER, tbl, start, qlens,
                                       interpret=True)
     np.testing.assert_allclose(
         np.asarray(out.reshape(B, C, KV, G, D)), np.asarray(ref),
@@ -125,11 +140,11 @@ def test_kernel_skips_padding_rows_and_garbage_tables(rng_key):
     start = jnp.array([2, 0], jnp.int32)
     qlens = jnp.array([4, 0], jnp.int32)
     qf = q.transpose(0, 2, 1, 3, 4).reshape(B, KV, C * G, D)
-    base = paged_prefill(qf, kp, vp, tbl, start, qlens, group=G,
+    base = paged_prefill(qf, kp, vp, LAYER, tbl, start, qlens, group=G,
                          interpret=True)
     assert not np.asarray(base[1]).any()               # padding row: zeros
     junk = tbl.at[0, 3].set(99999).at[1, 0].set(-5)    # past row 0's extent
-    out = paged_prefill(qf, kp, vp, junk, start, qlens, group=G,
+    out = paged_prefill(qf, kp, vp, LAYER, junk, start, qlens, group=G,
                         interpret=True)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
 

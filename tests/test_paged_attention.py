@@ -22,11 +22,15 @@ def qwen():
     return cfg, params
 
 
+LAYERS, LAYER = 3, 1      # a stacked pool; the kernels read its middle layer
+
+
 def _pages(key, B, KV, G, D, NP, page, pps, dtype=jnp.float32):
+    """q and a stacked (LAYERS, NP, page, KV * D) K/V pool of noise."""
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (B, KV, G, D), dtype)
-    kp = jax.random.normal(ks[1], (NP, page, KV, D), dtype)
-    vp = jax.random.normal(ks[2], (NP, page, KV, D), dtype)
+    kp = jax.random.normal(ks[1], (LAYERS, NP, page, KV * D), dtype)
+    vp = jax.random.normal(ks[2], (LAYERS, NP, page, KV * D), dtype)
     tbl = jax.random.randint(ks[3], (B, pps), 0, NP, jnp.int32)
     return q, kp, vp, tbl
 
@@ -46,12 +50,18 @@ def test_paged_kernel_matches_ref(B, KV, G, D, NP, page, pps, window,
     q, kp, vp, tbl = _pages(rng_key, B, KV, G, D, NP, page, pps)
     lens = jnp.array([1 + (7 * i) % (pps * page) for i in range(B)],
                      jnp.int32)
-    out = paged_attention(q, kp, vp, tbl, lens, window=window,
+    out = paged_attention(q, kp, vp, LAYER, tbl, lens, window=window,
                           softcap=softcap, interpret=True)
-    ref = paged_attention_ref(q, kp, vp, tbl, lens, window=window,
+    ref = paged_attention_ref(q, kp, vp, LAYER, tbl, lens, window=window,
                               softcap=softcap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
+    # the other layers' pages are never read
+    other = jnp.zeros_like(kp).at[LAYER].set(kp[LAYER])
+    np.testing.assert_array_equal(
+        np.asarray(paged_attention(q, other, vp, LAYER, tbl, lens,
+                                   window=window, softcap=softcap,
+                                   interpret=True)), np.asarray(out))
 
 
 def test_paged_ref_matches_dense_attention(rng_key):
@@ -59,23 +69,26 @@ def test_paged_ref_matches_dense_attention(rng_key):
     contiguous attention exactly: paging changes layout, not math."""
     B, KV, G, D, page, pps = 2, 2, 2, 16, 4, 4
     T = page * pps
-    ks = jax.random.split(rng_key, 3)
+    ks = jax.random.split(rng_key, 4)
     q = jax.random.normal(ks[0], (B, KV, G, D))
     k = jax.random.normal(ks[1], (B, T, KV, D))
     v = jax.random.normal(ks[2], (B, T, KV, D))
-    # scatter the dense sequences into pages via a permuted table
+    # scatter the dense sequences into one layer's pages via a permuted
+    # table (head h of position p in lanes [h*D, (h+1)*D) of its row);
+    # the other layers hold noise
     perm = np.random.default_rng(0).permutation(B * pps)
     tbl = jnp.asarray(perm.reshape(B, pps), jnp.int32)
-    kp = jnp.zeros((B * pps, page, KV, D))
-    vp = jnp.zeros((B * pps, page, KV, D))
+    noise = jax.random.normal(ks[3], (LAYERS, B * pps, page, KV * D))
+    kp, vp = noise, -noise
     for b in range(B):
         for j in range(pps):
-            kp = kp.at[perm[b * pps + j]].set(
-                k[b, j * page:(j + 1) * page])
-            vp = vp.at[perm[b * pps + j]].set(
-                v[b, j * page:(j + 1) * page])
+            rows = slice(j * page, (j + 1) * page)
+            kp = kp.at[LAYER, perm[b * pps + j]].set(
+                k[b, rows].reshape(page, KV * D))
+            vp = vp.at[LAYER, perm[b * pps + j]].set(
+                v[b, rows].reshape(page, KV * D))
     lens = jnp.array([T, T - 3], jnp.int32)
-    out = paged_attention_ref(q, kp, vp, tbl, lens)
+    out = paged_attention_ref(q, kp, vp, LAYER, tbl, lens)
     # dense oracle: fold (B, KV, G) and attend with the last-row slice
     for b in range(B):
         L = int(lens[b])
@@ -94,9 +107,10 @@ def test_ops_wrapper_gqa_layout(rng_key):
     B, KV, G, D, NP, page, pps = 2, 2, 3, 16, 7, 4, 3
     q, kp, vp, tbl = _pages(rng_key, B, KV, G, D, NP, page, pps)
     lens = jnp.array([5, 11], jnp.int32)
-    ref = paged_attention_ref(q, kp, vp, tbl, lens)
+    ref = paged_attention_ref(q, kp, vp, LAYER, tbl, lens)
     qm = q.reshape(B, 1, KV * G, D)
-    out = ops.paged_decode_attention(qm, kp, vp, tbl, lens, interpret=True)
+    out = ops.paged_decode_attention(qm, kp, vp, LAYER, tbl, lens,
+                                     interpret=True)
     np.testing.assert_allclose(
         np.asarray(out[:, 0].reshape(B, KV, G, D)), np.asarray(ref),
         atol=2e-6, rtol=2e-6)
@@ -108,9 +122,9 @@ def test_kernel_ignores_garbage_table_entries(rng_key):
     B, KV, G, D, NP, page, pps = 1, 1, 2, 16, 6, 4, 4
     q, kp, vp, tbl = _pages(rng_key, B, KV, G, D, NP, page, pps)
     lens = jnp.array([6], jnp.int32)                   # pages 2, 3 unused
-    base = paged_attention(q, kp, vp, tbl, lens, interpret=True)
+    base = paged_attention(q, kp, vp, LAYER, tbl, lens, interpret=True)
     junk = tbl.at[0, 2].set(99999).at[0, 3].set(-7)
-    out = paged_attention(q, kp, vp, junk, lens, interpret=True)
+    out = paged_attention(q, kp, vp, LAYER, junk, lens, interpret=True)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
 
 
@@ -122,9 +136,12 @@ def test_paged_cache_storage_and_tables(qwen):
     cfg, _ = qwen
     kv = PagedKVCache(cfg, max_slots=2, max_seq_len=32, block_size=8,
                       paged=True, num_blocks=5)
-    # storage: batch axis -> blocks (+1 trash), seq axis -> one block
-    leaf = jax.tree.leaves(kv.cache)[0]
-    assert leaf.shape[-4] == 6 and leaf.shape[-3] == 8
+    # storage: every layer's blocks (+1 trash) stacked, one block of
+    # positions per block, each position's KV heads side by side
+    for leaf in jax.tree.leaves(kv.cache):
+        assert leaf.shape == (cfg.num_layers, 6, 8,
+                              cfg.num_kv_heads * cfg.resolved_head_dim)
+        assert leaf.dtype == jnp.bfloat16
     s = kv.alloc_slot(prompt_len=10)                   # 2 blocks
     tbl = np.asarray(kv.device_block_tables())
     assert list(tbl[s, :2]) == kv.block_table[s]
@@ -256,3 +273,95 @@ def test_preempted_request_resumes_correctly(qwen):
     assert sched.preemptions > 0                       # really preempted
     for rid, ref in zip(rids, solo):
         np.testing.assert_array_equal(sched.output(rid), ref)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "gemma2-2b"])
+def test_paged_in_place_bit_identical_under_hits_preemption_interleaving(
+        arch):
+    """The pool updated in place through prefix hits, decode-time
+    preemption and budgeted prefill interleaving serves the dense
+    layout's greedy tokens bit for bit — for a qwen2-like stack and a
+    gemma2-like one (local/global pattern of 2 over two scan groups, so
+    layer l = 2 * group + i; sliding window; logit softcaps)."""
+    import dataclasses
+
+    from repro.configs import get_smoke_config
+    from repro.models import transformer as T
+    cfg = get_smoke_config(arch)
+    if cfg.local_global_pattern:
+        cfg = dataclasses.replace(cfg, num_layers=4)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(3)
+    shared = rng.integers(0, cfg.vocab_size, 16, dtype=np.int32)
+    prompts = [np.concatenate([shared, rng.integers(
+        0, cfg.vocab_size, n, dtype=np.int32)]) for n in (5, 11, 2, 14, 7, 9)]
+    sps = [SamplingParams(max_new_tokens=m, greedy=True)
+           for m in (12, 9, 14, 6, 10, 8)]
+
+    def serve(budget=None, **kw):
+        # raw argmax: bit-identity is the property pinned here
+        eng = ServingEngine(cfg, params, max_seq_len=48, max_slots=3,
+                            kv_block_size=8, prefill_chunk=8,
+                            prefill_batch=2, greedy_tie_eps=0.0, **kw)
+        sched = Scheduler(eng, prefill_token_budget=budget)
+        rids = [sched.submit(Request(p, sp)) for p, sp in zip(prompts, sps)]
+        interleaved = 0
+        while sched.has_work:
+            sched.step()
+            interleaved += bool(sched.prefilling and sched.active)
+        return [sched.output(r) for r in rids], eng, sched, interleaved
+
+    dense = serve(paged=False)[0]
+    # 8 blocks for 3 slots of up to 6: decode growth runs the pool dry
+    paged, eng, sched, interleaved = serve(
+        budget=8, paged=True, num_blocks=8, prefix_cache_blocks=8)
+    for a, b in zip(dense, paged):
+        np.testing.assert_array_equal(a, b)
+    assert eng.cached_prefix_tokens > 0                # prefix hits
+    assert sched.preemptions > 0                       # really preempted
+    assert interleaved > 0                             # decode mid-prefill
+    assert eng.kv.pool.in_use == 0
+
+
+def test_paged_programs_donate_the_pool_and_keep_the_tables(qwen):
+    """decode_once and a prefill round take the pool donated: the buffer
+    they were given is deleted, not kept alive beside the new one, while
+    the engine's cached device block tables (plain and masked) are not
+    donated and stay usable across both programs."""
+    cfg, params = qwen
+    eng = ServingEngine(cfg, params, max_seq_len=64, max_slots=3,
+                        kv_block_size=16, paged=True)
+    rng = np.random.default_rng(4)
+    long, short = (rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
+                   for n in (20, 9))
+    pos = np.zeros(eng.max_slots, np.int32)
+    toks = np.zeros(eng.max_slots, np.int32)
+
+    def donates(run):
+        before = jax.tree.leaves(eng.kv.cache)
+        out = run()
+        assert all(leaf.is_deleted() for leaf in before)
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves(eng.kv.cache))
+        return out
+
+    cursors = eng.begin_prefill([long, short])
+    done = donates(lambda: eng.advance_prefill(cursors, token_budget=1))
+    assert [c.slot for c in done] == [cursors[1].slot]       # long in flight
+
+    # decode with the in-flight slot masked: the masked upload is cached
+    masked = eng.kv.device_block_tables(mask_slots=eng._inflight)
+    pos[cursors[1].slot] = len(short)
+    donates(lambda: eng.decode_once(toks, pos))
+    assert not masked.is_deleted()
+    assert eng.kv.device_block_tables(mask_slots=eng._inflight) is masked
+
+    # finish the long prompt, then decode with the plain tables
+    donates(eng.advance_prefill)
+    tables = eng.kv.device_block_tables()
+    pos[cursors[0].slot] = len(long)
+    logits = donates(lambda: eng.decode_once(toks, pos))
+    assert not tables.is_deleted() and not masked.is_deleted()
+    assert eng.kv.device_block_tables() is tables
+    np.testing.assert_array_equal(np.asarray(tables), eng.kv._tables)
+    assert np.isfinite(np.asarray(logits)).all()

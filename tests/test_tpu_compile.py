@@ -10,21 +10,32 @@ chip of a described ``v5e:2x2`` topology, and assert that the compiled
 program holds the kernel (``tpu_custom_call``).  Nothing runs; no chip
 is needed.
 
+The engine's two paged programs (``jit_serve_step``, ``jit_prefill_paged``)
+are compiled whole, at qwen2-0.5b and deepseek-coder-33b widths, to
+check that they update the donated KV pool in place: no pool-sized
+copy, slice or update-slice, and the pool aliased from input to output.
+
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and the test
 workers all import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import ModelConfig
 from repro.kernels import ops
+from repro.models import transformer as T
+from repro.serving import ServingEngine
 
 H, KV, D, PAGE = 14, 2, 64, 16          # qwen2-0.5b attention widths
 PAGES_PER_SEQ = 64                      # max_seq_len 1024
+LAYERS = 2                              # layers of the stacked pool
 
 
 @pytest.fixture(scope="module")
@@ -65,9 +76,13 @@ def _assert_kernel(lowered):
 
 
 def _pages(one_chip, batch):
+    """The stacked pool (LAYERS, pages + trash, PAGE, KV * D), a layer
+    index and the block tables."""
     n = batch * PAGES_PER_SEQ + 1                        # + trash page
-    return (_spec(one_chip, (n, PAGE, KV, D), jnp.bfloat16),
-            _spec(one_chip, (n, PAGE, KV, D), jnp.bfloat16),
+    pool = (LAYERS, n, PAGE, KV * D)
+    return (_spec(one_chip, pool, jnp.bfloat16),
+            _spec(one_chip, pool, jnp.bfloat16),
+            _spec(one_chip, (), jnp.int32),
             _spec(one_chip, (batch, PAGES_PER_SEQ), jnp.int32))
 
 
@@ -75,9 +90,9 @@ def _pages(one_chip, batch):
 def test_paged_decode_attention_compiles(one_chip, no_compile_cache,
                                          window, softcap):
     B = 8
-    k, v, tables = _pages(one_chip, B)
+    k, v, layer, tables = _pages(one_chip, B)
     _assert_kernel(ops.paged_decode_attention.lower(
-        _spec(one_chip, (B, 1, H, D), jnp.bfloat16), k, v, tables,
+        _spec(one_chip, (B, 1, H, D), jnp.bfloat16), k, v, layer, tables,
         _spec(one_chip, (B,), jnp.int32),
         window=window, softcap=softcap, interpret=False))
 
@@ -86,11 +101,91 @@ def test_paged_decode_attention_compiles(one_chip, no_compile_cache,
 def test_paged_prefill_attention_compiles(one_chip, no_compile_cache,
                                           window, softcap):
     B, C = 4, 16
-    k, v, tables = _pages(one_chip, B)
+    k, v, layer, tables = _pages(one_chip, B)
     rows = _spec(one_chip, (B,), jnp.int32)
     _assert_kernel(ops.paged_prefill_attention.lower(
-        _spec(one_chip, (B, C, H, D), jnp.bfloat16), k, v, tables, rows,
-        rows, window=window, softcap=softcap, interpret=False))
+        _spec(one_chip, (B, C, H, D), jnp.bfloat16), k, v, layer, tables,
+        rows, rows, window=window, softcap=softcap, interpret=False))
+
+
+# ---------------------------------------------------------------------------
+# the engine's paged programs keep the pool in place
+# ---------------------------------------------------------------------------
+
+# (heads, kv heads, head dim, d_model, d_ff, vocab, tied, qkv bias, slots)
+WIDTHS = {
+    "qwen2-0.5b": (14, 2, 64, 896, 4864, 151_936, True, True, 32),
+    "deepseek-coder-33b": (56, 8, 128, 7168, 19_200, 32_256, False, False,
+                           16),
+}
+# pool blocks of the compiled programs (the trash block makes it odd, so
+# no other dimension matches it).  A pool that fits the chip's VMEM is
+# staged there by the compiler, which a deployment's pool never is; at
+# 4,097 blocks of two layers every pool leaf is too large for that.
+BLOCKS = 4097
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     re.M)
+# ops that move data (fusions are named after the ops they fuse)
+_MOVES = re.compile(r"copy|dynamic-(update-)?slice")
+
+
+def _pool_moves(hlo: str, layer_elems: int):
+    """The ops that copy, slice or update-slice a buffer of the pool's
+    block count holding at least one layer's pages."""
+    found = []
+    for name, dims, op in _HLO_OP.findall(hlo):
+        shape = [int(d) for d in dims.split(",") if d]
+        moves = _MOVES.fullmatch(op.replace("-start", "").replace(
+            "-done", "")) or (op == "fusion" and _MOVES.search(name))
+        if moves and BLOCKS in shape and np.prod(shape) >= layer_elems:
+            found.append(f"{op} {name} {shape}")
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_programs_update_the_pool_in_place(one_chip, no_compile_cache,
+                                                 monkeypatch, arch, program):
+    h, kv, d, d_model, d_ff, vocab, tied, bias, slots = WIDTHS[arch]
+    cfg = ModelConfig(name=arch, family="dense", num_layers=LAYERS,
+                      d_model=d_model, num_heads=h, num_kv_heads=kv,
+                      head_dim=d, d_ff=d_ff, vocab_size=vocab,
+                      qkv_bias=bias, tie_embeddings=tied, dtype="bfloat16",
+                      param_dtype="float32", kv_cache_dtype="bfloat16")
+    # the kernels compile for the described chip, not the interpreter
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    jax.clear_caches()
+    params = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: T.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    # the engine's own jitted programs, lowered at the chosen pool size
+    eng = ServingEngine(cfg, params, max_seq_len=4096, max_slots=slots,
+                        paged=True, num_blocks=8, prefill_batch=4)
+    pool = {name: _spec(one_chip, leaf.shape[:1] + (BLOCKS,)
+                        + leaf.shape[2:], leaf.dtype)
+            for name, leaf in eng.kv.cache.items()}
+    bps, i32 = eng.kv.blocks_per_slot, jnp.int32
+    if program == "decode":
+        compiled = eng._step.lower(params, {
+            "tokens": _spec(one_chip, (slots, 1), i32),
+            "positions": _spec(one_chip, (slots,), i32),
+            "block_tables": _spec(one_chip, (slots, bps), i32)},
+            pool).compile()
+        kernel = "paged_decode_attention"
+    else:
+        rows = _spec(one_chip, (4,), i32)
+        compiled = eng._prefill_paged.lower(
+            params, _spec(one_chip, (4, eng.prefill_chunk), i32), rows,
+            rows, pool, _spec(one_chip, (4, bps), i32)).compile()
+        kernel = "paged_prefill_attention"
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and kernel in hlo
+    layer_elems = BLOCKS * PAGE * kv * d
+    assert _pool_moves(hlo, layer_elems) == []
+    pool_bytes = sum(np.prod(a.shape) * a.dtype.itemsize
+                     for a in pool.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
 
 
 def test_mha_flash_attention_compiles(one_chip, no_compile_cache):
