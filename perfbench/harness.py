@@ -1,6 +1,7 @@
 """Run one cell of the benchmark: set up, warm up, measure, check.
 
-A cell names a configuration (``configs/<name>.json``) and a traffic mix
+A cell names a configuration (``configs/<name>.json``, whose ``family``
+names ``families/<family>.py``) and a traffic mix
 (``mixes/<traffic>.json``); each metric is read by ``metrics/<name>.py``.
 The harness finds all of them by the names in ``BENCHMARK.json``.
 
@@ -67,6 +68,9 @@ def load_cell(name: str, spec: Optional[dict] = None) -> Cell:
     w = wl[name]
     cf = {c["name"]: c for c in spec["configs"]}[w["config"]]
     config = load_json(ROOT / cf["file"])
+    if "family" not in config:
+        raise KeyError(f"{cf['file']} names no model family: a configuration "
+                       f"file needs a 'family' key (families/<family>.py)")
     mix = load_json(HERE / "mixes" / f"{w['traffic']}.json")
     return Cell(spec, w, config, mix)
 

@@ -110,7 +110,7 @@ def logits(weights, config: dict, tokens: np.ndarray, rows: np.ndarray,
     toks[:S] = tokens
     x = jnp.take(weights["embed"]["table"], jnp.asarray(toks), axis=0)
     for i in range(n["L"]):
-        x = _layer(x, model.layer(weights, i), n=_Frozen(n_key), mode=mode)
+        x = _layer(x, model.layer(n, weights, i), n=_Frozen(n_key), mode=mode)
     x = x[jnp.asarray(rows)]
     head_w = weights.get("lm_head", {}).get("w")
     out = _head(x, weights["final_norm"]["scale"], head_w,

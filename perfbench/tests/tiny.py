@@ -9,7 +9,7 @@ HERE = Path(__file__).resolve().parent
 BENCH = HERE.parent
 
 CONFIG = {
-    "name": "tiny-dense", "reference": "dense",
+    "name": "tiny-dense", "family": "dense", "reference": "dense",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 307, "rms_norm_eps": 1e-6, "rope_theta": 10000.0,

@@ -4,7 +4,9 @@ idle gaps.
 A trace is read into flat event lists: the device's operations and
 programs (from the planes and lines ``programs.json`` names), and the
 host thread that carries the benchmark's own ``TraceAnnotation`` spans.
-All times are seconds on the profiler's clock.
+Each kernel is declared in a file of its own, ``kernels/<kernel>.json``:
+the list of HLO name patterns of its operations.  All times are seconds
+on the profiler's clock.
 """
 from __future__ import annotations
 
@@ -15,7 +17,10 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
-NAMES = json.loads((Path(__file__).parent / "programs.json").read_text())
+HERE = Path(__file__).parent
+NAMES = json.loads((HERE / "programs.json").read_text())
+NAMES["kernels"] = {p.stem: json.loads(p.read_text())
+                    for p in sorted((HERE / "kernels").glob("*.json"))}
 
 Interval = Tuple[float, float]
 Event = Tuple[str, float, float]            # (name, start_s, end_s)
@@ -159,7 +164,7 @@ def module_time(tr: Trace, patterns: Sequence[str]) -> Tuple[float, int]:
 
 
 def kernel_seconds(tr: Trace, kernel: str) -> float:
-    """Device seconds of a kernel declared in ``programs.json``; raises
+    """Device seconds of a kernel declared in ``kernels/``; raises
     :class:`MissingKernel` when the trace holds none of its events."""
     pats = NAMES["kernels"][kernel]
     t, n = op_time(tr, pats)

@@ -12,14 +12,16 @@ from __future__ import annotations
 
 from typing import Iterable, Tuple
 
+from perfbench import model
+
 KV_BYTES = 2
 ACT_BYTES = 2
 
 
 def matmul_params(n: dict) -> int:
-    """Weights one token multiplies through in one layer."""
-    d, H, KV, D, F = n["d"], n["H"], n["KV"], n["D"], n["F"]
-    return d * H * D + 2 * d * KV * D + H * D * d + 3 * d * F
+    """Weights one token multiplies through in one layer, as the
+    configuration's family counts them."""
+    return model.family(n).matmul_params(n)
 
 
 def decode_attention(n: dict, ctx: Iterable[int]) -> Tuple[float, float]:
