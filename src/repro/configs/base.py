@@ -46,10 +46,18 @@ class ModelConfig:
     # (documented beyond-paper variant; gemma2 has local layers natively).
     long_context_window: Optional[int] = None
     attn_scale: Optional[float] = None               # default 1/sqrt(head_dim)
+    # per-head RMSNorm of q and k (gains of shape (head_dim,)), applied
+    # after the projections and before RoPE (qwen3)
+    qk_norm: bool = False
 
     # --- MoE ----------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0              # experts this chip holds
     num_experts_per_tok: int = 0
+    # the router's outputs: every expert of the layer, of which this chip
+    # holds ``num_experts`` starting at ``moe_first_expert`` (expert
+    # parallelism); 0 means the chip holds them all
+    moe_router_width: int = 0
+    moe_first_expert: int = 0
     moe_capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.01
     # dispatch-group size: tokens are routed in contiguous groups of this
@@ -112,6 +120,10 @@ class ModelConfig:
         return self.head_dim if self.head_dim is not None else self.d_model // self.num_heads
 
     @property
+    def router_width(self) -> int:
+        return self.moe_router_width or self.num_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -140,6 +152,8 @@ class ModelConfig:
             p = d * (h * dh) + 2 * d * (kv * dh) + (h * dh) * d
             if self.qkv_bias:
                 p += (h + 2 * kv) * dh
+            if self.qk_norm:
+                p += 2 * dh
             return p
 
         def mlp_params(dff: int) -> int:
@@ -150,7 +164,8 @@ class ModelConfig:
                 n += attn_params() + mlp_params(self.d_ff) + 2 * d
             elif self.family == "moe":
                 e = self.num_experts_per_tok if active_only else self.num_experts
-                n += attn_params() + e * mlp_params(self.d_ff) + d * self.num_experts + 2 * d
+                n += (attn_params() + e * mlp_params(self.d_ff)
+                      + d * self.router_width + 2 * d)
             elif self.family == "ssm":
                 di, ns = self.ssm_d_inner, self.ssm_state
                 g = self.ssm_groups

@@ -143,7 +143,8 @@ def make_serve_step(cfg, mesh: Mesh, strategy: str,
 
     def serve(params, batch):
         logits, new_cache = T.decode_step(params, cfg, batch,
-                                          long_context=long_context)
+                                          long_context=long_context,
+                                          capacity_moe=True)
         return logits, new_cache
 
     # donate the batch so the updated cache aliases the input cache buffers

@@ -12,6 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import expert_gmm as _eg
 from repro.kernels import flash_attention as _fa
 from repro.kernels import paged_attention as _pa
 from repro.kernels import paged_prefill as _pp
@@ -123,6 +124,25 @@ def paged_prefill_attention(q, k_pages, v_pages, layer, block_tables,
                             interpret=interpret)
     return out.reshape(B, KV, C, G, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, C, H, D)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def expert_gmm(x, wi, wg, wo, group_sizes, first=0, *, tm: int = 128,
+               interpret: Optional[bool] = None):
+    """The SwiGLU FFN of the held experts over rows sorted by expert.
+
+    x: (m, d) rows, ``group_sizes[g]`` of expert ``g`` after those of
+    ``g - 1`` (``m`` a multiple of ``tm``); wi, wg: (W, d, F); wo:
+    (W, F, d) at their stored dtype, expert ``g``'s at ``first + g`` (so
+    a layer's experts are read in place from every layer's stack).
+    Returns ``(out (m, d), executed)``, ``executed`` the rows the kernel
+    ran, tile padding included; rows past ``sum(group_sizes)`` hold no
+    defined value.
+    """
+    if interpret is None:
+        interpret = _default_interpret()
+    return _eg.expert_gmm(x, wi, wg, wo, group_sizes, first, tm=tm,
+                          interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -135,3 +135,19 @@ def rmsnorm_ref(x, scale, eps: float = 1e-6):
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     y = xf * jax.lax.rsqrt(var + eps) * (1.0 + scale.astype(jnp.float32))
     return y.astype(x.dtype)
+
+
+def expert_gmm_ref(x, wi, wg, wo, group_sizes, first=0):
+    """Rows sorted by group through their group's SwiGLU FFN (group
+    ``g``'s weights at ``first + g``), each row with its own expert's
+    weights gathered; rows past the groups' sum return zeros."""
+    ends = jnp.cumsum(group_sizes)
+    rows = jnp.arange(x.shape[0])
+    g = first + jnp.minimum(jnp.searchsorted(ends, rows, side="right"),
+                            group_sizes.shape[0] - 1)
+    xf = x.astype(jnp.float32)
+    gate = jnp.einsum("md,mdf->mf", xf, wg[g].astype(jnp.float32))
+    up = jnp.einsum("md,mdf->mf", xf, wi[g].astype(jnp.float32))
+    out = jnp.einsum("mf,mfd->md", jax.nn.silu(gate) * up,
+                     wo[g].astype(jnp.float32))
+    return jnp.where((rows < ends[-1])[:, None], out, 0.0).astype(x.dtype)

@@ -1,4 +1,5 @@
-"""Attention: GQA + RoPE / M-RoPE + sliding-window + logit softcap + KV cache.
+"""Attention: GQA + q/k norm + RoPE / M-RoPE + sliding-window + logit softcap +
+KV cache.
 
 The prefill/train path is *query-chunked* (a lax.scan over query blocks with
 per-chunk remat) so the S x S score matrix is never materialized — this is
@@ -59,12 +60,16 @@ def init_attention(key, cfg, cross: bool = False):
     d, h, kv, dh = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     kq, kk, kvk, ko = jax.random.split(key, 4)
     bias = cfg.qkv_bias and not cross
-    return {
+    p = {
         "wq": nn.init_linear(kq, d, (h, dh), bias=bias),
         "wk": nn.init_linear(kk, d, (kv, dh), bias=bias),
         "wv": nn.init_linear(kvk, d, (kv, dh), bias=bias),
         "wo": nn.init_linear(ko, h * dh, d, stddev=1.0 / np.sqrt(h * dh)),
     }
+    if cfg.qk_norm and not cross:
+        p["q_norm"] = nn.init_rmsnorm(dh)
+        p["k_norm"] = nn.init_rmsnorm(dh)
+    return p
 
 
 # ---------------------------------------------------------------------------
@@ -174,6 +179,11 @@ def attention_block(params, cfg, x, *, positions=None, causal: bool = True,
     kv_src = x if kv_override is None else kv_override.astype(dt)
     k = nn.linear(params["wk"], kv_src, dtype=dt)                # (B,Skv,kv,dh)
     v = nn.linear(params["wv"], kv_src, dtype=dt)
+    if cfg.qk_norm and kv_override is None:
+        # per-head RMSNorm before RoPE, so the K written to any cache is
+        # already normed
+        q = nn.rmsnorm(params["q_norm"], q, cfg.norm_eps)
+        k = nn.rmsnorm(params["k_norm"], k, cfg.norm_eps)
 
     if use_rope and kv_override is None:
         sections = cfg.mrope_sections if cfg.mrope else None

@@ -1,4 +1,19 @@
-"""Mixture-of-Experts: top-k router + capacity-bounded sort-based dispatch.
+"""Mixture-of-Experts: top-k router; capacity-bounded dispatch for
+training, the dropless expert-share layer for serving.
+
+Serving (:func:`expert_share_block`): the layer is told which experts
+this chip holds (``cfg.moe_first_expert`` and ``cfg.num_experts`` of the
+router's ``cfg.router_width`` outputs).  Each real token is routed over
+every router output in float32 (softmax, top-k, the k gates renormalised
+over themselves); its assignments to held experts are sorted by expert
+and computed in one grouped matmul (``kernels/expert_gmm.py``), with no
+capacity, so no assignment is dropped and a token's output never depends
+on what else is in the step.  Padding tokens and inactive decode rows
+(``valid`` false) get no expert rows.  What the experts held elsewhere
+would add is left out: on one chip the layer runs without the exchange
+that expert parallelism puts around it.
+
+Training (:func:`moe_block`) keeps the grouped capacity dispatch below.
 
 TPU adaptation: instead of the GShard one-hot-einsum dispatch (whose
 (tokens, experts, capacity) dispatch tensor is quadratically large for the
@@ -28,7 +43,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.models import modules as nn
+
+ROW_TILE = 128          # rows of the grouped matmul's tiles
 
 
 def init_moe(key, cfg):
@@ -36,7 +54,7 @@ def init_moe(key, cfg):
     kr, ki, kg, ko = jax.random.split(key, 4)
     s = 1.0 / np.sqrt(d)
     return {
-        "router": nn.init_linear(kr, d, e),
+        "router": nn.init_linear(kr, d, cfg.router_width),
         # expert-stacked gated MLP weights: leading dim = experts
         "wi": nn.truncated_normal_init(ki, (e, d, dff), s),
         "wg": nn.truncated_normal_init(kg, (e, d, dff), s),
@@ -139,3 +157,55 @@ def moe_block(params, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
     out = jnp.sum((slot_out.astype(jnp.float32)
                    * w[..., None]).reshape(G, Tg, K, d), axis=2)
     return out.reshape(B, S, d).astype(x.dtype), aux
+
+
+def route(params, cfg, x):
+    """Router of every expert, in float32: ``(gates (T, K), experts
+    (T, K))`` for tokens ``x`` (T, d), the K gates renormalised to sum
+    to 1."""
+    logits = jnp.dot(x.astype(jnp.float32),
+                     params["router"]["w"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gate, eidx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                               cfg.num_experts_per_tok)
+    return gate / jnp.sum(gate, axis=-1, keepdims=True), eidx
+
+
+def expert_share_block(params, cfg, x, valid, layer=0):
+    """Dropless serving expert layer over the experts this chip holds.
+
+    x: (B, S, d); valid: (B, S) bool, false for padding tokens and
+    inactive decode rows.  ``params["router"]`` is this layer's router;
+    ``params["wi"]``, ``["wg"]``, ``["wo"]`` hold ``E`` experts a layer,
+    this layer's from index ``layer * E``: one layer's (``layer`` 0), or
+    every layer's stacked, which the grouped matmul reads in place.
+    Returns ``(out (B, S, d), counts)``: ``counts["rows"]`` (E,) int32,
+    the assignments of valid tokens to each held expert, and
+    ``counts["executed"]``, the rows the grouped matmul ran (tile padding
+    included).
+    """
+    B, S, d = x.shape
+    T, E, K = B * S, cfg.num_experts, cfg.num_experts_per_tok
+    xf = x.reshape(T, d)
+    gate, eidx = route(params, cfg, xf)
+    local = eidx - cfg.moe_first_expert
+    held = ((local >= 0) & (local < E)
+            & valid.reshape(T, 1)).reshape(T * K)
+    # each assignment's row among the rows sorted by held expert; the
+    # assignments of no held expert (group E) follow them, uncomputed
+    group = jnp.where(held, local.reshape(T * K), E)
+    onehot = (group[:, None] == jnp.arange(E + 1)[None]).astype(jnp.int32)
+    sizes = jnp.sum(onehot, axis=0)
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    row = jnp.cumsum(sizes)[group] - sizes[group] + rank
+    m = -(-(T * K) // ROW_TILE) * ROW_TILE
+    token = jnp.zeros((m,), jnp.int32).at[row].set(
+        jnp.arange(T * K, dtype=jnp.int32) // K)
+    y, executed = ops.expert_gmm(
+        xf.astype(cfg.dtype)[token], params["wi"], params["wg"],
+        params["wo"], sizes[:E], layer * E, tm=ROW_TILE)
+    part = jnp.where(held[:, None], y[row].astype(jnp.float32)
+                     * gate.reshape(T * K, 1), 0.0)
+    out = jnp.sum(part.reshape(T, K, d), axis=1)
+    return (out.reshape(B, S, d).astype(x.dtype),
+            {"rows": sizes[:E], "executed": executed})

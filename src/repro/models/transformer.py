@@ -3,7 +3,8 @@
 Families:
   dense   — llama-style pre-norm GQA decoder (+ gemma2 local/global pattern,
             logit softcaps, post-block norms; qwen2 QKV bias)
-  moe     — dense attention + top-k MoE FFN (dbrx, qwen3-moe)
+  moe     — dense attention + top-k MoE FFN (dbrx, qwen3-moe); serving
+            runs the dropless expert-share layer over the experts held
   ssm     — Mamba2 (SSD) stack, attention-free
   hybrid  — Mamba2 backbone + one weight-SHARED attention block applied
             every ``hybrid_attn_every`` layers (zamba2)
@@ -82,9 +83,14 @@ def _stack_init(fn, keys):
 def _apply_attn_mlp_layer(p, cfg, x, *, window, positions=None, causal=True,
                           cache=None, cache_index=None, encoder_out=None,
                           use_rope=True, block_tables=None, q_lens=None,
-                          layer=None):
+                          layer=None, expert_share=None):
     """Pre-norm attention + (cross-attention) + MLP/MoE.  Returns
-    (x, new_cache, aux)."""
+    (x, new_cache, aux).  MoE layers of the serving programs, which pass
+    ``expert_share`` = (valid (B, S), every layer's stacked expert
+    weights from :func:`_held_experts`), run the dropless expert-share
+    layer over the valid tokens with this ``layer``'s experts, and aux
+    is its row counts (:func:`moe_lib.expert_share_block`); training's
+    run the capacity dispatch, and aux is the load-balance loss."""
     h = _norm(cfg, p["ln1"], x)
     a, new_cache = attn.attention_block(
         p["attn"], cfg, h, positions=positions, causal=causal, window=window,
@@ -100,7 +106,11 @@ def _apply_attn_mlp_layer(p, cfg, x, *, window, positions=None, causal=True,
         x = x + c
     h = _norm(cfg, p["ln2"], x)
     aux = jnp.zeros((), jnp.float32)
-    if "moe" in p:
+    if "moe" in p and expert_share is not None:
+        valid, experts = expert_share
+        m, aux = moe_lib.expert_share_block({**p["moe"], **experts}, cfg, h,
+                                            valid, layer)
+    elif "moe" in p:
         m, aux = moe_lib.moe_block(p["moe"], cfg, h)
     else:
         m = nn.mlp(p["mlp"], h, cfg.act, dtype=jnp.dtype(cfg.dtype))
@@ -416,11 +426,30 @@ def _encode(enc_params, cfg, encoder_input):
 # Decode step (one token against a seq_len cache)
 # ---------------------------------------------------------------------------
 
+def _held_experts(cfg, layers):
+    """The serving programs' split of a MoE stack: the stacked layer tree
+    without the experts' weights, for the layer scan, and those weights
+    of every layer as ``(layers * E, ...)`` stacks, which the grouped
+    matmul reads in place (a scanned slice of them would be copied out,
+    every call, for the kernel).  Other families pass through."""
+    if cfg.family != "moe":
+        return layers, None
+    m = layers["moe"]
+    experts = {k: m[k].reshape((-1,) + m[k].shape[-2:])
+               for k in ("wi", "wg", "wo")}
+    return dict(layers, moe={"router": m["router"]}), experts
+
+
 def decode_step(params, cfg, batch: Dict[str, Any], *,
-                long_context: bool = False) -> Tuple[jnp.ndarray, Any]:
+                long_context: bool = False,
+                capacity_moe: bool = False) -> Tuple[jnp.ndarray, Any]:
     """One-token decode against the dense per-slot cache.  batch: tokens
     (B,1), positions (B,), cache, plus encoder_output / mrope_positions
-    when applicable.  (The paged pool decodes through
+    when applicable, and for an MoE model ``valid`` (B,) bool, the rows
+    that serve a sequence (every row when absent).  MoE layers run the
+    dropless expert-share layer over the valid rows, as served; with
+    ``capacity_moe`` they run training's capacity dispatch instead (the
+    sharded dry-run decode).  (The paged pool decodes through
     :func:`prefill_step` with one-token chunks.)  Returns (logits
     (B, 1, V) f32, new_cache)."""
     dt = jnp.dtype(cfg.dtype)
@@ -441,7 +470,30 @@ def decode_step(params, cfg, batch: Dict[str, Any], *,
     use_rope = cfg.max_pos_embed == 0
     new_cache = dict(cache)
 
-    if cfg.family in ("dense", "vlm", "moe", "encdec"):
+    if cfg.family == "moe" and not capacity_moe:
+        layers, experts = _held_experts(cfg, params["layers"])
+        valid = batch.get("valid", jnp.ones((B,), bool))[:, None]  # (B,1)
+        groups = jax.tree.leaves(layers)[0].shape[0]
+
+        def body(x, xs):
+            gp, gc, g = xs
+            ncs = []
+            for i, win in enumerate(windows):
+                lp = jax.tree.map(lambda a: a[i], gp)
+                lc = jax.tree.map(lambda a: a[i], gc)
+                x, nc_i, _ = _apply_attn_mlp_layer(
+                    lp, cfg, x, window=win, positions=positions, cache=lc,
+                    cache_index=idx, use_rope=use_rope,
+                    layer=g * len(windows) + i, expert_share=(valid, experts))
+                ncs.append(nc_i)
+            return x, jax.tree.map(lambda *a: jnp.stack(a), *ncs)
+
+        x, nc = _scan_layers(
+            body, x, layers,
+            (cache["layers"], jnp.arange(groups, dtype=jnp.int32)), False,
+            cfg.scan_layers)
+        new_cache["layers"] = nc
+    elif cfg.family in ("dense", "vlm", "moe", "encdec"):
         def body(x, xs):
             if cfg.family == "encdec":
                 lp, lc = xs
@@ -532,14 +584,21 @@ def prefill_step(params, cfg, batch: Dict[str, Any], *,
     through the tables with the Pallas paged-prefill kernel, so paged
     prefill never materializes a dense ``max_seq_len`` stripe.  With
     C == 1 this is one paged decode step: the attention layer writes
-    each row's K/V at its position and runs the paged decode kernel.
+    each row's K/V at its position and runs the paged decode kernel;
+    batch ``valid`` (B,) then marks the rows that serve a sequence (a
+    chunk's real tokens are those within ``q_lens``).  MoE layers run the
+    dropless expert-share layer over the real tokens only.
     The whole stacked pool rides in the layer loop's carry: layer ``l``
     (scan group ``g``, member ``i``: ``l = g * len(pattern) + i``)
     scatters its rows into ``pool[:, l]`` in place and its kernel reads
     ``(l, page)`` blocks of the same buffer, so no layer's pages are
     sliced out, relaid out or restacked.  Returns (logits (B, C, V) f32,
-    new pool); logits at padding positions are garbage — callers index
-    only real tokens.
+    new pool, expert counts); logits at padding positions are garbage —
+    callers index only real tokens.  The expert counts are ``{}`` unless
+    the family is moe: then ``"expert_rows"`` (L, E) int32, each held
+    expert's rows of real tokens in each layer, and
+    ``"expert_rows_executed"`` (L,), the rows each layer's grouped
+    matmul ran.
     """
     if cfg.family not in ("dense", "vlm", "moe"):
         raise ValueError(
@@ -560,29 +619,49 @@ def prefill_step(params, cfg, batch: Dict[str, Any], *,
 
     windows = layer_pattern(cfg, long_context)
     use_rope = cfg.max_pos_embed == 0
+    layers, experts = _held_experts(cfg, params["layers"])
+    share = None
+    if experts is not None:
+        if "valid" in batch:                # one-token decode: live rows
+            valid = jnp.broadcast_to(batch["valid"][:, None], (B, C))
+        else:                               # a chunk's real tokens
+            q_lens = batch.get("q_lens", jnp.full((B,), C, jnp.int32))
+            valid = jnp.arange(C)[None] < q_lens[:, None]
+        share = (valid, experts)
 
     def body(carry, xs):
         x, pool = carry
         gp, g = xs
+        counts = []
         for i, win in enumerate(windows):
             lp = jax.tree.map(lambda a: a[i], gp)
-            x, pool, _ = _apply_attn_mlp_layer(
+            x, pool, aux = _apply_attn_mlp_layer(
                 lp, cfg, x, window=win, positions=positions, cache=pool,
                 cache_index=starts, use_rope=use_rope,
                 block_tables=batch["block_tables"],
-                q_lens=batch.get("q_lens"), layer=g * len(windows) + i)
-        return (x, pool), None
+                q_lens=batch.get("q_lens"), layer=g * len(windows) + i,
+                expert_share=share)
+            counts.append(aux)
+        if share is None:
+            return (x, pool), None
+        return (x, pool), jax.tree.map(lambda *a: jnp.stack(a), *counts)
 
-    groups = jax.tree.leaves(params["layers"])[0].shape[0]
-    (x, pool), _ = _scan_layers(
-        body, (x, batch["cache"]), params["layers"],
+    groups = jax.tree.leaves(layers)[0].shape[0]
+    (x, pool), counts = _scan_layers(
+        body, (x, batch["cache"]), layers,
         (jnp.arange(groups, dtype=jnp.int32),), False, cfg.scan_layers)
+    expert_counts = {}
+    if counts is not None:
+        L = cfg.num_layers
+        expert_counts = {
+            "expert_rows": counts["rows"].reshape(L, -1),
+            "expert_rows_executed": counts["executed"].reshape(L)}
 
     x = _norm(cfg, params["final_norm"], x)
     logits = (nn.unembed(params["embed"], x) if cfg.tie_embeddings
               else nn.linear(params["lm_head"], x, dtype=dt).astype(jnp.float32))
     logits = nn.softcap(logits, cfg.final_logit_softcap)
-    return logits, pool
+    return logits, pool, expert_counts
 
 
 # ---------------------------------------------------------------------------

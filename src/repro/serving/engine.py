@@ -136,11 +136,12 @@ def make_serve_step(cfg, *, long_context: bool = False):
 
 
 def make_paged_serve_step(cfg):
-    """serve_step(params, batch, pool) -> (logits, new_pool): the paged
-    decode program, a one-token ``T.prefill_step`` chunk per row.  batch
-    carries tokens (B,1), positions (B,) and block_tables; the pool
-    rides alone so that it, and only it, can be donated — the engine's
-    cached device block tables stay alive."""
+    """serve_step(params, batch, pool) -> (logits, new_pool, expert
+    counts): the paged decode program, a one-token ``T.prefill_step``
+    chunk per row.  batch carries tokens (B,1), positions (B,) and
+    block_tables (and ``valid`` (B,) for an MoE model); the pool rides
+    alone so that it, and only it, can be donated — the engine's cached
+    device block tables stay alive."""
     def serve_step(params, batch, pool):
         return T.prefill_step(params, cfg, {**batch, "cache": pool})
     return serve_step
@@ -220,6 +221,14 @@ class ServingEngine:
         self.prefill_tokens_padding = 0      # executed - real
         self.cached_prefix_tokens = 0        # tokens served from the store
         self.transient_prefill_bytes = 0     # peak batch-1 staging cache
+        # MoE (paged): rows the held experts got from real tokens, and
+        # the rows their grouped matmul ran, as read back so far.  Each
+        # program's counts stay on the device until the step's sampled
+        # tokens are read back, and come back with them
+        self.moe = cfg.family == "moe"
+        self.expert_rows = 0
+        self.expert_rows_executed = 0
+        self._expert_counts: List[tuple] = []   # (device counts, tokens)
         # bound by the scheduler that drives this engine (one tracer per
         # replica); None until then — engine-side trace emission is
         # guarded so direct primitive use stays untraced
@@ -506,12 +515,14 @@ class ServingEngine:
                         self.recompiles.observe(
                             "prefill_paged", (toks.shape, tables.shape),
                             tracer=tr)
-                        logits, self.kv.cache = self._prefill_paged(
+                        logits, self.kv.cache, experts = self._prefill_paged(
                             self.params, jnp.asarray(toks),
                             jnp.asarray(starts), jnp.asarray(qlens),
                             self.kv.cache, jnp.asarray(tables))
                         real = int(qlens.sum())
                         sp.set(real_tokens=real)
+                        if experts:
+                            self._expert_counts.append((experts, real))
                     # FLOPs proxy: every row of the compiled (Bp, C)
                     # program executes every round, dummy rows included
                     spent += Bp * C
@@ -611,6 +622,12 @@ class ServingEngine:
                     if s not in self._inflight))
             batch = {"tokens": jnp.asarray(tokens, jnp.int32)[:, None],
                      "positions": jnp.asarray(positions, jnp.int32)}
+            if self.moe:
+                # rows of free and mid-prefill slots get no expert rows
+                rows = np.zeros(np.shape(tokens), bool)
+                rows[[s for s in self.kv.block_table
+                      if s not in self._inflight]] = True
+                batch["valid"] = jnp.asarray(rows)
             self.recompiles.observe(
                 "decode_step", (np.shape(tokens), np.shape(positions)),
                 tracer=self.tracer)
@@ -623,8 +640,10 @@ class ServingEngine:
                 # the prefill just produced
                 batch["block_tables"] = self.kv.device_block_tables(
                     mask_slots=self._inflight)
-                logits, self.kv.cache = self._step(self.params, batch,
-                                                   self.kv.cache)
+                logits, self.kv.cache, experts = self._step(
+                    self.params, batch, self.kv.cache)
+                if experts:
+                    self._expert_counts.append((experts, live))
             else:
                 batch["cache"] = self.kv.cache
                 if self._enc_pool is not None:
@@ -641,11 +660,36 @@ class ServingEngine:
         with span(self.tracer, "engine.sample", rows=shape[0]):
             self.key, sub = jax.random.split(self.key)
             self.recompiles.observe("sample", shape, tracer=self.tracer)
+            toks = self._sample_vec(sub, jnp.asarray(logits),
+                                    jnp.asarray(temps, jnp.float32),
+                                    jnp.asarray(greedy))
             # deliberate: THE one host sync per step — the scheduler
             # needs concrete token ids for EOS/retirement bookkeeping
-            return np.asarray(self._sample_vec(  # repro-lint: disable=RL001
-                sub, jnp.asarray(logits), jnp.asarray(temps, jnp.float32),
-                jnp.asarray(greedy)))
+            if not self._expert_counts:
+                return np.asarray(toks)  # repro-lint: disable=RL001
+            # the expert counts of the programs run since the last
+            # read-back ride on the same one
+            toks, counts = jax.device_get(  # repro-lint: disable=RL001
+                (toks, self._expert_counts))
+            self._expert_counts = []
+            self._record_expert_rows(counts)
+            return toks
+
+    def _record_expert_rows(self, counts: List[tuple]) -> None:
+        """Add read-back expert counts to the engine's totals and to the
+        scheduler's metrics: real tokens routed, rows routed to held
+        experts, rows the grouped matmul ran, (layer, expert) pairs that
+        got a row (each reads that expert's weights) and grouped-matmul
+        calls (one a layer a program)."""
+        for c, tokens in counts:
+            rows, executed = c["expert_rows"], c["expert_rows_executed"]
+            self.expert_rows += int(rows.sum())
+            self.expert_rows_executed += int(executed.sum())
+            if self.tracer is not None:
+                self.tracer.expert_rows(
+                    tokens=tokens, rows=int(rows.sum()),
+                    executed=int(executed.sum()),
+                    experts_hit=int((rows > 0).sum()), calls=len(executed))
 
     def free_slot(self, slot: int) -> None:
         self.kv.free_slot(slot)

@@ -35,6 +35,11 @@ from repro.serving.slo import (SlidingWindow, TenantStats,
                                merge_tenant_summaries)
 
 DEFAULT_SAMPLE_CAP = 4096
+# the MoE counters of ``summary()["moe"]`` (present once an MoE program
+# ran): real tokens routed, their rows to held experts, the rows the
+# grouped matmul ran, (layer, expert) pairs with a row, and calls
+MOE_COUNTS = ("tokens", "expert_rows", "expert_rows_executed",
+              "experts_hit", "calls")
 
 
 def _pct(xs: List[float], q: float) -> float:
@@ -130,6 +135,8 @@ class ServingMetrics:
         self.budget_rounds: int = 0
         self.budget_tokens_executed: int = 0
         self.budget_tokens_cap: int = 0
+        # MoE expert layer (engine read-back; see record_expert_rows)
+        self.moe: Dict[str, int] = dict.fromkeys(MOE_COUNTS, 0)
         # per-tenant rollups
         self.tenants: Dict[str, TenantStats] = {}
         self._tenant_of: Dict[int, str] = {}      # in-flight rids only
@@ -261,6 +268,19 @@ class ServingMetrics:
         self.prefill_tokens_real += real
         self.prefill_tokens_executed += executed
 
+    def record_expert_rows(self, *, tokens: int, rows: int, executed: int,
+                           experts_hit: int, calls: int) -> None:
+        """MoE programs' read-back counts: real ``tokens`` routed,
+        ``rows`` they gave the held experts, ``executed`` rows the
+        grouped matmul ran (tile padding included), ``experts_hit``
+        (layer, expert) pairs with a row, and grouped-matmul ``calls``."""
+        m = self.moe
+        m["tokens"] += tokens
+        m["expert_rows"] += rows
+        m["expert_rows_executed"] += executed
+        m["experts_hit"] += experts_hit
+        m["calls"] += calls
+
     def record_budget(self, executed: int, budget: int) -> None:
         """One budgeted prefill round: ``executed`` token positions ran
         against a cap of ``budget`` (utilization may exceed 1.0 — the
@@ -360,6 +380,7 @@ class ServingMetrics:
             },
             "tenants": {name: t.summary()
                         for name, t in sorted(self.tenants.items())},
+            **({"moe": dict(self.moe)} if self.moe["calls"] else {}),
         }
 
     def _decode_gap_summary(self) -> Dict[str, float]:
@@ -397,6 +418,7 @@ def merge_summaries(summaries: List[Dict[str, object]]) -> Dict[str, object]:
     cached = sum(p["cached_tokens_served"] for p in pc)
     prompt = sum(p["prompt_tokens"] for p in pc)
     pf = [s["prefill_tokens"] for s in summaries if "prefill_tokens" in s]
+    moe = [s for s in summaries if "moe" in s]
     pf_real = sum(p["real"] for p in pf)
     pf_exec = sum(p["executed"] for p in pf)
     # jitter percentiles: only replicas that actually decoded carry
@@ -471,4 +493,6 @@ def merge_summaries(summaries: List[Dict[str, object]]) -> Dict[str, object]:
         # nothing, extending the jitter-dilution regression to tenants
         "tenants": merge_tenant_summaries(
             [s.get("tenants", {}) for s in summaries]),
+        **({"moe": {k: sum(s["moe"][k] for s in moe) for k in MOE_COUNTS}}
+           if moe else {}),
     }

@@ -315,6 +315,11 @@ class Tracer:
     def budget_round(self, executed: int, budget: int) -> None:
         self.metrics.record_budget(executed, budget)
 
+    def expert_rows(self, **counts: int) -> None:
+        """Read-back expert counts of the MoE programs run since the last
+        read-back (metrics-only, no event)."""
+        self.metrics.record_expert_rows(**counts)
+
     def decode_tokens(self, rids) -> None:
         """One decode step emitted tokens for ``rids`` (per-tenant
         inter-token gap recording; metrics-only, no event)."""

@@ -10,7 +10,10 @@ chip of a described ``v5e:2x2`` topology, and assert that the compiled
 program holds the kernel (``tpu_custom_call``).  Nothing runs; no chip
 is needed.
 
-The engine's two paged programs (``jit_serve_step``, ``jit_prefill_paged``)
+The grouped expert matmul of the MoE layer compiles at qwen3-moe-30b-a3b
+widths, and the engine's MoE programs hand it the stacked expert weights
+with no per-layer copy.  The engine's two paged programs
+(``jit_serve_step``, ``jit_prefill_paged``)
 are compiled whole, at qwen2-0.5b and deepseek-coder-33b widths, to
 check that they update the donated KV pool in place: no pool-sized
 copy, slice or update-slice, and the pool aliased from input to output.
@@ -186,6 +189,79 @@ def test_paged_programs_update_the_pool_in_place(one_chip, no_compile_cache,
     pool_bytes = sum(np.prod(a.shape) * a.dtype.itemsize
                      for a in pool.values())
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_expert_gmm_compiles(one_chip, no_compile_cache):
+    """The grouped expert matmul at qwen3-moe-30b-a3b widths (d 2,048,
+    experts of 768, 32 held) over a decode step's 1,024 assignment rows
+    (128 slots, 8 experts a token), reading one layer's experts from the
+    float32 masters of all 8 layers, stacked."""
+    m, d, F, G, W = 1024, 2048, 768, 32, 8 * 32
+    f32 = jnp.float32
+    compiled = ops.expert_gmm.lower(
+        _spec(one_chip, (m, d), jnp.bfloat16),
+        _spec(one_chip, (W, d, F), f32), _spec(one_chip, (W, d, F), f32),
+        _spec(one_chip, (W, F, d), f32), _spec(one_chip, (G,), jnp.int32),
+        _spec(one_chip, (), jnp.int32), interpret=False).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "expert_gmm" in hlo
+
+
+def _moves_of(hlo: str, elems: int):
+    """The ops that copy, slice or update-slice a buffer of at least
+    ``elems`` elements."""
+    found = []
+    for name, dims, op in _HLO_OP.findall(hlo):
+        shape = [int(d) for d in dims.split(",") if d]
+        moves = _MOVES.fullmatch(op.replace("-start", "").replace(
+            "-done", "")) or (op == "fusion" and _MOVES.search(name))
+        if moves and np.prod(shape) >= elems:
+            found.append(f"{op} {name} {shape}")
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_moe_programs_read_the_experts_in_place(one_chip, no_compile_cache,
+                                                monkeypatch, program):
+    """The engine's paged MoE programs at qwen3-moe-30b-a3b widths (two
+    layers of 32 held experts of a 128-wide router) hand the grouped
+    matmul every layer's stacked expert weights: no copy or slice of a
+    layer's experts (32 x 2,048 x 768 float32) is made per call."""
+    E, d, F = 32, 2048, 768
+    cfg = ModelConfig(name="qwen3-moe-stage", family="moe", num_layers=2,
+                      d_model=d, num_heads=32, num_kv_heads=4, head_dim=128,
+                      d_ff=F, num_experts=E, num_experts_per_tok=8,
+                      moe_router_width=128, qk_norm=True,
+                      vocab_size=151_936, tie_embeddings=False,
+                      dtype="bfloat16", param_dtype="float32",
+                      kv_cache_dtype="bfloat16")
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    jax.clear_caches()
+    params = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: T.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    slots, i32 = 128, jnp.int32
+    eng = ServingEngine(cfg, params, max_seq_len=1152, max_slots=slots,
+                        paged=True, num_blocks=8, prefill_batch=4)
+    pool = {name: _spec(one_chip, leaf.shape, leaf.dtype)
+            for name, leaf in eng.kv.cache.items()}
+    bps = eng.kv.blocks_per_slot
+    if program == "decode":
+        compiled = eng._step.lower(params, {
+            "tokens": _spec(one_chip, (slots, 1), i32),
+            "positions": _spec(one_chip, (slots,), i32),
+            "valid": _spec(one_chip, (slots,), jnp.bool_),
+            "block_tables": _spec(one_chip, (slots, bps), i32)},
+            pool).compile()
+    else:
+        rows = _spec(one_chip, (4,), i32)
+        compiled = eng._prefill_paged.lower(
+            params, _spec(one_chip, (4, eng.prefill_chunk), i32), rows,
+            rows, pool, _spec(one_chip, (4, bps), i32)).compile()
+    hlo = compiled.as_text()
+    assert "expert_gmm" in hlo
+    assert _moves_of(hlo, E * d * F) == []
 
 
 def test_mha_flash_attention_compiles(one_chip, no_compile_cache):
