@@ -2,8 +2,10 @@
 
 Params are nested dicts of jnp arrays.  Every ``init_*`` takes a PRNG key
 and returns a params pytree; every ``apply``-style function is pure.
-Compute happens in ``cfg.dtype`` (bf16 by default); params are stored in
-``cfg.param_dtype`` (f32 master copies).
+Compute happens in ``cfg.dtype`` (bf16 by default); params are made in
+``cfg.param_dtype`` (f32 master copies).  A serving engine holds
+``cfg.dtype`` copies of the weights that every use casts to it
+(``transformer.serving_params``), so those casts are no-ops there.
 """
 from __future__ import annotations
 

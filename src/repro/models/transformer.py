@@ -196,6 +196,47 @@ def init_params(cfg, key) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
+# Serving weights: held in the compute dtype where every use casts to it
+# ---------------------------------------------------------------------------
+
+# modules whose ``w`` and ``b`` every program runs through
+# ``nn.linear(..., dtype=cfg.dtype)``, which casts them at each use
+_COMPUTE_DTYPE_MODULES = ("attn", "cross_attn", "mlp")
+
+
+def serving_params(cfg, params):
+    """The tree a serving engine holds: every leaf that each program casts
+    to ``cfg.dtype`` at every use, already in ``cfg.dtype``; every other
+    leaf as given.  ``astype`` to a leaf's own dtype is a no-op, so the
+    programs compute the same numbers, reading 2-byte weights where a
+    float32 master is read (4 bytes), cast (2 written) and read again in
+    every call.
+
+    In ``cfg.dtype``: the attention projections and their biases (a bias
+    is added in the projection's dtype), the dense MLP, the embedding
+    table (gathered in ``cfg.dtype``; a tied head multiplies it by the
+    final hidden state, which is in ``cfg.dtype``) and ``lm_head``.  As
+    given: norm gains, q/k norms included (``rmsnorm`` applies them in
+    float32); the MoE router (routes in float32); the expert stacks (the
+    grouped matmul casts them in VMEM); the SSM leaves; position tables.
+    A tree of ``jax.ShapeDtypeStruct`` (shapes to lower programs from)
+    maps to one."""
+    dt = jnp.dtype(cfg.dtype)
+
+    def hold(path, a):
+        keys = [getattr(k, "key", None) for k in path]
+        if not (keys[0] in ("embed", "lm_head")
+                or (len(keys) >= 3 and keys[-1] in ("w", "b")
+                    and keys[-3] in _COMPUTE_DTYPE_MODULES)):
+            return a
+        if isinstance(a, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(a.shape, dt, sharding=a.sharding)
+        return a.astype(dt)
+
+    return jax.tree_util.tree_map_with_path(hold, params)
+
+
+# ---------------------------------------------------------------------------
 # KV / SSM cache
 # ---------------------------------------------------------------------------
 

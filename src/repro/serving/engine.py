@@ -170,6 +170,11 @@ class ServingEngine:
     ``device`` places the engine's parameters and KV pool on one device
     (several one-chip replicas in one process); every program the engine
     runs then follows them there.  ``None`` keeps JAX's default device.
+
+    ``self.params`` holds the weights that every program casts to
+    ``cfg.dtype`` already in it (:func:`T.serving_params`), so no program
+    call casts them; ``weight_bytes`` gives the bytes held in each dtype,
+    which the scheduler's metrics report under ``"weights"``.
     """
 
     def __init__(self, cfg, params, max_seq_len: int, max_slots: int = 8,
@@ -181,7 +186,13 @@ class ServingEngine:
         self.cfg = cfg
         if device is not None:
             params = jax.device_put(params, device)
-        self.params = params
+        # cast once here, not in every program call
+        self.params = T.serving_params(cfg, params)
+        self.weight_bytes: Dict[str, int] = {}   # dtype name -> bytes held
+        for a in jax.tree.leaves(self.params):
+            k = a.dtype.name
+            self.weight_bytes[k] = (self.weight_bytes.get(k, 0)
+                                    + a.size * a.dtype.itemsize)
         self.max_seq_len = max_seq_len
         self.max_slots = max_slots
         self.key = jax.random.PRNGKey(rng_seed)

@@ -137,6 +137,8 @@ class ServingMetrics:
         self.budget_tokens_cap: int = 0
         # MoE expert layer (engine read-back; see record_expert_rows)
         self.moe: Dict[str, int] = dict.fromkeys(MOE_COUNTS, 0)
+        # bytes of weights the engine holds, by dtype name
+        self.weights: Dict[str, int] = {}
         # per-tenant rollups
         self.tenants: Dict[str, TenantStats] = {}
         self._tenant_of: Dict[int, str] = {}      # in-flight rids only
@@ -380,6 +382,7 @@ class ServingMetrics:
             },
             "tenants": {name: t.summary()
                         for name, t in sorted(self.tenants.items())},
+            "weights": dict(self.weights),
             **({"moe": dict(self.moe)} if self.moe["calls"] else {}),
         }
 
@@ -419,6 +422,10 @@ def merge_summaries(summaries: List[Dict[str, object]]) -> Dict[str, object]:
     prompt = sum(p["prompt_tokens"] for p in pc)
     pf = [s["prefill_tokens"] for s in summaries if "prefill_tokens" in s]
     moe = [s for s in summaries if "moe" in s]
+    weights: Dict[str, int] = {}
+    for s in summaries:
+        for dtype, n in s.get("weights", {}).items():
+            weights[dtype] = weights.get(dtype, 0) + n
     pf_real = sum(p["real"] for p in pf)
     pf_exec = sum(p["executed"] for p in pf)
     # jitter percentiles: only replicas that actually decoded carry
@@ -493,6 +500,7 @@ def merge_summaries(summaries: List[Dict[str, object]]) -> Dict[str, object]:
         # nothing, extending the jitter-dilution regression to tenants
         "tenants": merge_tenant_summaries(
             [s.get("tenants", {}) for s in summaries]),
+        "weights": weights,
         **({"moe": {k: sum(s["moe"][k] for s in moe) for k in MOE_COUNTS}}
            if moe else {}),
     }

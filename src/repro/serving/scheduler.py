@@ -144,6 +144,7 @@ class Scheduler:
                             clock=clock)
         self.tracer = tracer
         self.metrics = tracer.metrics
+        self.metrics.weights = dict(engine.weight_bytes)
         engine.tracer = tracer
         engine.kv.tracer = tracer
         if engine.prefix_cache is not None:

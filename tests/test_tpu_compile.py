@@ -16,7 +16,9 @@ with no per-layer copy.  The engine's two paged programs
 (``jit_serve_step``, ``jit_prefill_paged``)
 are compiled whole, at qwen2-0.5b and deepseek-coder-33b widths, to
 check that they update the donated KV pool in place: no pool-sized
-copy, slice or update-slice, and the pool aliased from input to output.
+copy, slice or update-slice, and the pool aliased from input to output;
+and, built from the tree the engine holds, that they convert no float32
+weight to the compute dtype.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and the test
@@ -145,10 +147,18 @@ def _pool_moves(hlo: str, layer_elems: int):
     return found
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-@pytest.mark.parametrize("arch", sorted(WIDTHS))
-def test_paged_programs_update_the_pool_in_place(one_chip, no_compile_cache,
-                                                 monkeypatch, arch, program):
+@pytest.fixture(scope="module")
+def paged_compiles():
+    """(arch, program) -> (engine, f32 tree, pool, compiled): the pool and
+    weight tests read the same compile."""
+    return {}
+
+
+def _paged_program(compiles, one_chip, monkeypatch, arch, program):
+    """The engine's paged ``program`` at ``arch`` widths, compiled for the
+    described chip from the tree the engine holds."""
+    if (arch, program) in compiles:
+        return compiles[arch, program]
     h, kv, d, d_model, d_ff, vocab, tied, bias, slots = WIDTHS[arch]
     cfg = ModelConfig(name=arch, family="dense", num_layers=LAYERS,
                       d_model=d_model, num_heads=h, num_kv_heads=kv,
@@ -170,18 +180,30 @@ def test_paged_programs_update_the_pool_in_place(one_chip, no_compile_cache,
             for name, leaf in eng.kv.cache.items()}
     bps, i32 = eng.kv.blocks_per_slot, jnp.int32
     if program == "decode":
-        compiled = eng._step.lower(params, {
+        compiled = eng._step.lower(eng.params, {
             "tokens": _spec(one_chip, (slots, 1), i32),
             "positions": _spec(one_chip, (slots,), i32),
             "block_tables": _spec(one_chip, (slots, bps), i32)},
             pool).compile()
-        kernel = "paged_decode_attention"
     else:
         rows = _spec(one_chip, (4,), i32)
         compiled = eng._prefill_paged.lower(
-            params, _spec(one_chip, (4, eng.prefill_chunk), i32), rows,
+            eng.params, _spec(one_chip, (4, eng.prefill_chunk), i32), rows,
             rows, pool, _spec(one_chip, (4, bps), i32)).compile()
-        kernel = "paged_prefill_attention"
+    compiles[arch, program] = eng, params, pool, compiled
+    return compiles[arch, program]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_programs_update_the_pool_in_place(one_chip, no_compile_cache,
+                                                 paged_compiles, monkeypatch,
+                                                 arch, program):
+    _, _, pool, compiled = _paged_program(paged_compiles, one_chip,
+                                          monkeypatch, arch, program)
+    kv, d = WIDTHS[arch][1:3]
+    kernel = {"decode": "paged_decode_attention",
+              "prefill": "paged_prefill_attention"}[program]
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and kernel in hlo
     layer_elems = BLOCKS * PAGE * kv * d
@@ -189,6 +211,39 @@ def test_paged_programs_update_the_pool_in_place(one_chip, no_compile_cache,
     pool_bytes = sum(np.prod(a.shape) * a.dtype.itemsize
                      for a in pool.values())
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+_HLO_TYPE = re.compile(r"%(\S+) = (\w+)\[([\d,]*)\]")
+_HLO_CONVERT = re.compile(r"= \w+\[[\d,]*\]\S* convert\(%([^,)\s]+)\)")
+
+
+def _f32_converts(hlo: str):
+    """The shapes of the float32 operands that the program converts (the
+    compiled text names an operand; its type is on its own line)."""
+    types = {name: (dt, tuple(int(x) for x in dims.split(",") if x))
+             for name, dt, dims in _HLO_TYPE.findall(hlo)}
+    return {types[a][1] for a in _HLO_CONVERT.findall(hlo)
+            if types.get(a, ("",))[0] == "f32"}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_programs_cast_no_weights(one_chip, no_compile_cache,
+                                        paged_compiles, monkeypatch, arch,
+                                        program):
+    """Compiled from the tree the engine holds, the paged programs convert
+    no float32 weight to the compute dtype: not a whole stack, not one
+    layer's slice."""
+    eng, params, _, compiled = _paged_program(paged_compiles, one_chip,
+                                              monkeypatch, arch, program)
+    shapes = set()
+    for path, a in jax.tree_util.tree_leaves_with_path(params):
+        shapes.add(a.shape)
+        if getattr(path[0], "key", None) == "layers":
+            shapes.add(a.shape[2:])          # one layer of (group, member)
+    held = jax.tree.leaves(eng.params)
+    assert any(a.dtype == jnp.bfloat16 for a in held)
+    assert _f32_converts(compiled.as_text()) & shapes == set()
 
 
 def test_expert_gmm_compiles(one_chip, no_compile_cache):
