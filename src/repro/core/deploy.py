@@ -79,7 +79,7 @@ class DeploymentPipeline:
                              self.cluster)
         log.append(f"ch-tar2dir: {unpacked}")
         # 5. Slurm submission script (paper §IV-B/C command lines)
-        from repro.launch import slurm
+        from repro.core import slurm
         script = slurm.render_script(
             job_name=definition.name, image_dir=str(unpacked),
             entrypoint=definition.entrypoint, nodes=nodes,

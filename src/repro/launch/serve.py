@@ -110,9 +110,9 @@ def main(argv=None):
                          "like ttft_p95_ms / gap_p95_ms; breaches land "
                          "in the trace as slo_breach events")
     ap.add_argument("--profile", action="store_true",
-                    help="paged-kernel cost/roofline profiles + "
-                         "recompile telemetry (step phases are program "
-                         "spans: take a jax.profiler trace)")
+                    help="print each replica's recompile telemetry "
+                         "(kernel times: take a jax.profiler trace, where "
+                         "the step phases are program spans)")
     ap.add_argument("--metrics-interval-steps", type=int, default=None,
                     metavar="N",
                     help="with --metrics-out: atomically re-write the "
@@ -259,20 +259,6 @@ def main(argv=None):
             print(f"recompiles [{rep.name}]: {rs['compiles_total']} "
                   f"compilations, {rs['post_warm_recompiles']} post-warm, "
                   f"churning: {rs['churning'] or 'none'}")
-        if args.paged:
-            from repro.serving import profile_paged_kernels
-            def share(frac):
-                return "not measured" if frac is None else f"{frac:.1%}"
-
-            for name, prof in profile_paged_kernels(
-                    gateway.replicas[0].scheduler.engine).items():
-                print(f"kernel {name} [{prof['device']}]: "
-                      f"{prof['wall_ms_median']:.2f} ms, "
-                      f"{prof['flops']:.3g} flops, "
-                      f"{prof['achieved_tflops']:.3f} TFLOP/s "
-                      f"({share(prof['fraction_of_peak_flops'])} of peak), "
-                      f"{prof['achieved_gbps']:.1f} GB/s "
-                      f"({share(prof['fraction_of_peak_bw'])} of HBM)")
     if args.metrics_json:
         with open(args.metrics_json, "w") as f:
             json.dump(stats, f, indent=2, sort_keys=True, default=str)

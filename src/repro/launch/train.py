@@ -1,6 +1,6 @@
 """Production training launcher.
 
-In-capsule entrypoint: the Slurm script generated by ``repro.launch.slurm``
+In-capsule entrypoint: the Slurm script generated by ``repro.core.slurm``
 runs ``ch-run <image> -- python -m repro.launch.train --arch <id> ...`` on
 every rank.  On real hardware the mesh spans the Slurm allocation (one
 process per chip host); here it spans whatever devices exist.

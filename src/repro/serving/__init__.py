@@ -21,8 +21,7 @@ from repro.serving.kvcache import KVBlockPool, OutOfBlocks, PagedKVCache
 from repro.serving.metrics import (ServingMetrics, atomic_write_json,
                                    merge_summaries)
 from repro.serving.prefix_cache import PrefixCache, PrefixCacheStats
-from repro.serving.profiling import (RecompilationTracker, profile_kernel,
-                                     profile_paged_kernels)
+from repro.serving.profiling import RecompilationTracker
 from repro.serving.scheduler import Scheduler
 from repro.serving.slo import (SLOConfig, SLOMonitor, SLOPolicy,
                                SlidingWindow, TenantStats,

@@ -5,7 +5,7 @@ container-on-HPC stacks separate *what* to launch from *who* launches
 it:
 
 * :class:`SlurmBackend` — renders a real sbatch script through
-  :func:`repro.launch.slurm.render_script` (the paper's submission
+  :func:`repro.core.slurm.render_script` (the paper's submission
   pattern: ``ch-run`` inside an exclusive allocation) into the spool's
   ``jobs/`` directory and tracks the job lifecycle
   PENDING -> RUNNING -> COMPLETED / FAILED off the worker's heartbeat
@@ -298,7 +298,7 @@ class SlurmBackend(SchedulerBackend):
     def _launch(self, handle: JobHandle) -> None:
         import shlex
 
-        from repro.launch import slurm
+        from repro.core import slurm
         spec = handle.spec
         argv = spec_to_args(spec.spool, spec.replica, spec.model_spec,
                             spec.image_dir)

@@ -72,7 +72,6 @@ class RemoteScheduler:
 
     # surface the gateway reads but a remote replica cannot offer
     prefix_cache = None
-    max_admissions_per_step = None
     prefill_token_budget = None
 
     def __init__(self, backend: SchedulerBackend, spec: WorkerSpec, *,

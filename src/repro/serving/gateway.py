@@ -533,7 +533,6 @@ class ReplicaGateway:
             inj = old.fault_injector
             new = Scheduler(
                 old.engine, tracer=old.tracer,
-                max_admissions_per_step=old.max_admissions_per_step,
                 prefill_token_budget=old.prefill_token_budget,
                 fault_injector=inj)
             new._next_rid = old._next_rid

@@ -108,7 +108,6 @@ class Scheduler:
     def __init__(self, engine: ServingEngine,
                  metrics: Optional[ServingMetrics] = None,
                  clock=time.perf_counter,
-                 max_admissions_per_step: Optional[int] = None,
                  prefill_token_budget: Optional[int] = None,
                  tracer: Optional[Tracer] = None,
                  fault_injector=None):
@@ -120,10 +119,6 @@ class Scheduler:
         # check per step
         self.fault_injector = fault_injector
         engine.fault_injector = fault_injector
-        # cap on requests admitted per scheduler step (None = drain all
-        # that fit).  1 reproduces the old one-at-a-time admission — the
-        # benchmark baseline — and smooths decode latency under bursts.
-        self.max_admissions_per_step = max_admissions_per_step
         # SplitFuse knob: max *executed* prefill token positions per
         # step (None = unbudgeted wave-at-once).  Each step then fuses
         # at most this much chunked prefill with one decode round, so
@@ -301,18 +296,15 @@ class Scheduler:
     def _admit(self) -> int:
         """Batched admission: claim slots + pins and register in-flight
         prefill cursors (no chunk rounds yet — those run under the
-        budget in ``_advance_prefill``).  Returns how many requests were
-        admitted (the step loop uses this to tell a capped-but-
-        progressing round from a genuine admission deadlock)."""
+        budget in ``_advance_prefill``).  Admission stops when slots or
+        blocks run out or no request can join the batch.  Returns how
+        many requests were admitted (the step loop uses this to tell a
+        round that made progress from a genuine admission deadlock)."""
         admitted = 0
         pc = self.prefix_cache
         while self.queue and self.engine.kv.free_slot_count > 0:
-            limit = self.engine.prefill_batch
-            if self.max_admissions_per_step is not None:
-                limit = min(limit, self.max_admissions_per_step - admitted)
-            if limit <= 0:
-                return admitted
-            states, seqs, starts, blocks_lists = self._collect_batch(limit)
+            states, seqs, starts, blocks_lists = self._collect_batch(
+                self.engine.prefill_batch)
             if not states:
                 return admitted
             try:
@@ -574,8 +566,8 @@ class Scheduler:
                     "admission deadlock: queue non-empty, no active "
                     "sequences, and prefill still cannot get blocks")
             else:
-                # everything admitted this step retired at its first
-                # token (or the admission cap paused the queue)
+                # the queue is drained, or everything admitted this
+                # step retired at its first token
                 ret = bool(self.queue) or admitted > 0 or completed > 0
             return ret, False, admitted, completed, executed
         with tr.span("scheduler.grow") as sp:

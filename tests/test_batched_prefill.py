@@ -201,8 +201,8 @@ def test_chunked_prefill_matches_oneshot(qwen, chunk, paged):
 @pytest.mark.parametrize("chunk", [5, 16])
 def test_generate_greedy_bit_identical_dense_vs_paged_vs_serial(qwen, chunk):
     """End-to-end greedy outputs are bit-identical across the dense
-    layout, batched paged co-admission, and one-at-a-time paged
-    admission."""
+    layout, batched paged co-admission, and one-row paged prefill
+    (``prefill_batch=1``)."""
     cfg, params = qwen
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
@@ -210,11 +210,11 @@ def test_generate_greedy_bit_identical_dense_vs_paged_vs_serial(qwen, chunk):
     sps = [SamplingParams(max_new_tokens=m, greedy=True)
            for m in (6, 4, 7, 3, 5, 6)]
 
-    def serve(paged, prefill_batch, serial=False):
+    def serve(paged, prefill_batch):
         eng = ServingEngine(cfg, params, max_seq_len=32, max_slots=4,
                             kv_block_size=8, prefill_chunk=chunk,
                             paged=paged, prefill_batch=prefill_batch)
-        sched = Scheduler(eng, max_admissions_per_step=1 if serial else None)
+        sched = Scheduler(eng)
         rids = [sched.submit(Request(p, sp))
                 for p, sp in zip(prompts, sps)]
         sched.run()
@@ -222,7 +222,7 @@ def test_generate_greedy_bit_identical_dense_vs_paged_vs_serial(qwen, chunk):
 
     dense = serve(False, 4)
     batched = serve(True, 4)
-    serial = serve(True, 1, serial=True)
+    serial = serve(True, 1)
     for a, b, c in zip(dense, batched, serial):
         np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(a, c)
@@ -286,6 +286,20 @@ def test_paged_prefill_allocates_no_dense_stripe(qwen, monkeypatch):
     eng.free_slot(slot)
 
 
+def test_dense_prefill_allocates_a_transient_stripe(qwen):
+    """The contrast to the paged case above: the dense layout prefills
+    the same prompt through a batch-1 staging cache, and the
+    transient-bytes telemetry records it."""
+    cfg, params = qwen
+    eng = ServingEngine(cfg, params, max_seq_len=64, max_slots=2,
+                        kv_block_size=16)
+    prompt = (np.arange(64, dtype=np.int32) * 3 + 1) % cfg.vocab_size
+    slot, last = eng.prefill_into_slot(prompt.astype(np.int32))
+    assert last is not None and last.shape == (cfg.vocab_size,)
+    assert eng.transient_prefill_bytes > 0
+    eng.free_slot(slot)
+
+
 def test_prefill_padding_accounting(qwen):
     """real vs executed vs padding: one wave of the compiled (Bp, C)
     program runs rounds * C * Bp token positions; the split shows up in
@@ -325,15 +339,15 @@ def test_decode_once_keeps_logits_on_device(qwen):
     eng.free_slot(slot)
 
 
-def test_capped_admission_first_token_retire_is_not_deadlock(qwen):
-    """Regression: with max_admissions_per_step=1, a request that
-    retires at its first sampled token leaves no active sequence while
-    the queue is non-empty — that's a capped-but-progressing round, not
-    an admission deadlock."""
+def test_first_token_retire_with_queue_waiting_is_not_deadlock(qwen):
+    """Regression: with 2 slots and 3 requests that retire at their
+    first sampled token, a step can leave no active sequence while the
+    queue is non-empty — a round that made progress, not an admission
+    deadlock."""
     cfg, params = qwen
     eng = ServingEngine(cfg, params, max_seq_len=32, max_slots=2,
                         kv_block_size=8)
-    sched = Scheduler(eng, max_admissions_per_step=1)
+    sched = Scheduler(eng)
     rids = [sched.submit(Request(np.array([1 + i, 2, 3], np.int32),
                                  SamplingParams(max_new_tokens=1,
                                                 greedy=True)))

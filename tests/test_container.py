@@ -150,7 +150,7 @@ def test_site_policy_rejects_docker_singularity_admits_charliecloud():
 
 
 def test_slurm_script_single_vs_multi():
-    from repro.launch import slurm
+    from repro.core import slurm
     s1 = slurm.render_script("j", "/img", "python", nodes=1)
     assert "mpiexec" not in s1 and "ch-run /img" in s1
     s2 = slurm.render_script("j", "/img", "python", nodes=16)
@@ -158,7 +158,7 @@ def test_slurm_script_single_vs_multi():
 
 
 def test_slurm_omp_threads_clamp():
-    from repro.launch import slurm
+    from repro.core import slurm
     s = slurm.render_script("j", "/img", "python", threads_per_rank=96)
     assert "export OMP_NUM_THREADS=48" in s
     # a 1-cpu rank must not render OMP_NUM_THREADS=0 (would disable
@@ -168,7 +168,7 @@ def test_slurm_omp_threads_clamp():
 
 
 def test_slurm_env_values_are_shell_quoted():
-    from repro.launch import slurm
+    from repro.core import slurm
     s = slurm.render_script(
         "j", "/img", "python",
         env={"SPOOL": "/tmp/my spool/dir",

@@ -782,7 +782,7 @@ def test_fabric_capacity_validated_before_submit(tmp_path):
 
 
 def test_fabric_slurm_backend_renders_and_tracks_lifecycle(tmp_path):
-    """SlurmBackend renders a real sbatch script through launch/slurm
+    """SlurmBackend renders a real sbatch script through core/slurm
     (shell-quoted worker argv, fabric env) and tracks the job off the
     worker's spool signals: heartbeat -> RUNNING, status -> COMPLETED."""
     import json as _json

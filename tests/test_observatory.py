@@ -394,28 +394,6 @@ def test_steady_state_zero_postwarm_then_injected_churn_warns(qwen):
 
 
 # ---------------------------------------------------------------------------
-# kernel profiler
-# ---------------------------------------------------------------------------
-
-def test_profile_paged_kernels_structure(qwen):
-    from repro.serving import profile_paged_kernels
-    eng = _engine(qwen, paged=True)
-    profs = profile_paged_kernels(eng, reps=1)
-    assert set(profs) == {"paged_attention", "paged_prefill"}
-    for prof in profs.values():
-        assert prof["wall_ms_median"] > 0.0
-        assert prof["flops"] > 0.0
-        assert prof["bytes_accessed"] > 0.0
-        assert prof["arithmetic_intensity"] > 0.0
-        # the CPU has no published peak: fractions are "not measured"
-        assert prof["device"] == "cpu"
-        assert prof["fraction_of_peak_flops"] is None
-        assert prof["fraction_of_peak_bw"] is None
-    with pytest.raises(ValueError, match="paged"):
-        profile_paged_kernels(_engine(qwen))   # dense engine refused
-
-
-# ---------------------------------------------------------------------------
 # end-to-end: tenants + SLO breaches through a real run, then the report
 # ---------------------------------------------------------------------------
 
@@ -489,7 +467,7 @@ def test_trace_report_empty_sections_warn_and_fail_validate(tmp_path,
 def test_serve_launcher_observatory_flags(qwen, tmp_path, capsys):
     """The CLI path (satellite b): --tenant/--slo-config/--profile/
     --metrics-out with periodic atomic flushes.  --profile prints the
-    recompile and kernel lines; step phases are program spans."""
+    recompile lines; step phases are program spans."""
     from repro.launch import serve
     slo_path = tmp_path / "slo.json"
     slo_path.write_text(json.dumps(

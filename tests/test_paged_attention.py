@@ -254,6 +254,44 @@ def test_undersized_pool_stress_no_drops_no_leaks(qwen):
     assert eng.kv.prefix_pool.in_use == 0
 
 
+def test_paged_pool_holds_more_sequences_than_dense_at_equal_kv_bytes(qwen):
+    """The claim paging exists for: at the same physical KV bytes (the
+    dense stripes equal the paged blocks, trash block included), block
+    tables serve strictly more concurrent sequences than the dense
+    worst-case layout, with bit-identical greedy outputs."""
+    cfg, params = qwen
+    block, max_seq_len, dense_slots, paged_slots = 16, 96, 3, 9
+    num_blocks = dense_slots * (max_seq_len // block) - 1
+    rng = np.random.default_rng(0)
+    shared = rng.integers(0, cfg.vocab_size, 3, dtype=np.int32)
+    prompts = [np.concatenate([shared,
+                               rng.integers(0, cfg.vocab_size,
+                                            4 + (i * 3) % 13,
+                                            dtype=np.int32)])
+               for i in range(12)]
+
+    def serve(**kw):
+        eng = ServingEngine(cfg, params, max_seq_len=max_seq_len,
+                            kv_block_size=block, **kw)
+        sched = Scheduler(eng)
+        rids = [sched.submit(Request(p, SamplingParams(max_new_tokens=6,
+                                                       greedy=True)))
+                for p in prompts]
+        peak = 0
+        while sched.has_work:
+            sched.step()
+            peak = max(peak, len(sched.active))
+        return [sched.output(r) for r in rids], peak, eng
+
+    dense, dense_peak, dense_eng = serve(max_slots=dense_slots)
+    paged, paged_peak, paged_eng = serve(max_slots=paged_slots, paged=True,
+                                         num_blocks=num_blocks)
+    assert paged_eng.kv.kv_bytes() == dense_eng.kv.kv_bytes()
+    assert paged_peak > dense_peak, (paged_peak, dense_peak)
+    for a, b in zip(dense, paged):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_preempted_request_resumes_correctly(qwen):
     """Force a decode-time preemption and check the deferred request's
     final output still matches its solo greedy run."""
